@@ -139,8 +139,9 @@ def main(small: bool = False, batch: int = 8, iters: int = 5,
     # greedy decode of an repro.models LM with every projection executing
     # through the decode-fused codr_matmul backend (interpret mode on
     # CPU), HBM bytes measured on the stored pack
+    from repro.configs import get_config, smoke_variant
     from repro.launch.serve import run_serve
-    st = run_serve(arch="qwen2.5-3b", batch=2,
+    st = run_serve(smoke_variant(get_config("qwen2.5-3b")), batch=2,
                    prompt_len=4 if small else 8,
                    gen_len=4 if small else 16,
                    use_codr=True, verbose=False)
@@ -156,7 +157,6 @@ def main(small: bool = False, batch: int = 8, iters: int = 5,
     # with concurrency because every pooled decode step amortizes one
     # packed weight fetch over all active slots
     import jax as _jax
-    from repro.configs import get_config, smoke_variant
     from repro.core.batching import ContinuousBatcher
     from repro.models import get_model
 

@@ -20,6 +20,7 @@ import sys
 
 from benchmarks import compression, decode, energy, engine, kernels, \
     roofline, sram_access
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig6": compression.main,
@@ -41,6 +42,7 @@ def main(argv=None) -> None:
                     help="CI smoke sizes for the suites that support it "
                          f"({', '.join(sorted(SMALL_AWARE))})")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.only:
         suites = {args.only: SUITES[args.only]}
     else:                       # run each suite once despite name aliases

@@ -68,10 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:                                   # jax >= 0.6 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                    # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as _P
 
 from repro.core import smm, ucr
@@ -378,6 +375,13 @@ class SmmKernelBackend(Backend):
     name = "smm_kernel"
     _caps: BackendCaps | None = None
 
+    def supports(self, layer) -> tuple[bool, str]:
+        if jax.default_backend() == "tpu":
+            from repro.kernels.smm_conv import ops as smm_ops
+            return False, (f"backend {self.name!r} does not run on a TPU: "
+                           f"{smm_ops.TPU_REFUSAL}")
+        return super().supports(layer)
+
     @property
     def caps(self) -> BackendCaps:
         # resolved lazily from the kernel's own KERNEL_CAPS so merely
@@ -589,6 +593,13 @@ class ShardedBackend(Backend):
         state = (mesh, w_sh, jax.jit(fwd))
         layer._shard_state = state
         return state
+
+    def placement(self, layer) -> dict[int, tuple[int, ...]]:
+        """Device id → shape of the slice of ``layer``'s tile stack that
+        device holds (preparing the layer first)."""
+        _, w_sh, _ = self._prepare(layer)
+        return {s.device.id: tuple(s.data.shape)
+                for s in w_sh.addressable_shards}
 
     # -- execution ----------------------------------------------------------
     def conv(self, layer, x):

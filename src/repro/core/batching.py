@@ -321,6 +321,13 @@ class ContinuousBatcher(AsyncWorkerLoop):
         return sum(leaf.size * leaf.dtype.itemsize
                    for leaf in jax.tree_util.tree_leaves(self._pool))
 
+    def lower_decode_step(self) -> jax.stages.Lowered:
+        """The pooled decode step lowered for this pool's shapes — what
+        ``.compile().as_text()`` inspects (e.g. for the Mosaic kernel's
+        ``tpu_custom_call``)."""
+        z = jnp.zeros((self.n_slots,), jnp.int32)
+        return self._step_fn.lower(self._params, self._pool, z, z)
+
     # -- paged-KV bookkeeping (all under self._cv) ---------------------------
     def _pages_ok_locked(self) -> bool:
         """Can the head pending request reserve its full page budget?"""

@@ -788,16 +788,18 @@ class CodrBatchServer(AsyncWorkerLoop):
             return
         staged: list = [None] * len(chunks)
         if chunks:                      # stage the first transfer
-            staged[0] = _try_device_put(chunks[0][1])
+            staged[0] = _stage(chunks[0][1])
         for i, (chunk_pos, batch, n_real, bucket) in enumerate(chunks):
             try:
-                y_dev = self.model.run(jnp.asarray(staged[i]))
+                if isinstance(staged[i], Exception):
+                    raise staged[i]     # its transfer failed
+                y_dev = self.model.run(staged[i])
             except Exception as e:      # noqa: BLE001 — lands on futures
                 y_dev, err = None, e
             else:
                 err = None
             if i + 1 < len(chunks):     # overlaps with batch i's compute
-                staged[i + 1] = _try_device_put(chunks[i + 1][1])
+                staged[i + 1] = _stage(chunks[i + 1][1])
             if err is None:
                 try:
                     y = np.asarray(y_dev)   # block on batch i only
@@ -835,15 +837,14 @@ class CodrBatchServer(AsyncWorkerLoop):
                 futs[p].set_result(y[j])
 
 
-def _try_device_put(batch: np.ndarray):
-    """Start the async host→device transfer for a staged batch.  On a
-    backend without ``device_put`` semantics this degrades to the host
-    array (the dispatch then transfers synchronously, still correct)."""
+def _stage(batch: np.ndarray):
+    """Start the async host→device transfer for a staged batch.  A
+    failed transfer is returned, not raised: the dispatch loop fails
+    exactly that batch's futures with it."""
     try:
-        return jax.device_put(jnp.asarray(batch))
-    # codrlint: disable=exception-hygiene — deliberate fallback: any device_put failure degrades to the host array; dispatch stays correct, just synchronous
-    except Exception:                   # pragma: no cover — defensive
-        return batch
+        return jax.device_put(batch)
+    except Exception as e:              # noqa: BLE001 — lands on futures
+        return e
 
 
 def codr_serving_stats(cfg, *, n_unique: int = 16, seed: int = 0,

@@ -1,7 +1,7 @@
 """jit'd public wrapper for the CoDR compressed matmul.
 
-On CPU (this container) the Pallas kernel runs in interpret mode; on a
-real TPU backend ``interpret=False`` compiles to Mosaic.
+Off-TPU the Pallas kernel runs in interpret mode; on a TPU backend
+``interpret=False`` compiles to Mosaic.
 """
 from __future__ import annotations
 
@@ -28,7 +28,7 @@ def _on_tpu() -> bool:
 
 
 def codr_matmul(x: jax.Array, w: PackedWeight, *, bm: int = 128,
-                bn: int = 128, bk: int = 128,
+                bn: int = 2048, bk: int = 512,
                 interpret: bool | None = None) -> jax.Array:
     """``y = x @ decode(w)`` with the decode fused into the matmul tiles."""
     if interpret is None:

@@ -14,7 +14,7 @@ docs/DESIGN.md §2):
   entries routes a ``(RO, CO)`` window of the selected product ``P[u]``
   into the output accumulator of its output channel (dynamic slice +
   dynamic store = the interconnection network).  A convolution stride
-  becomes a *strided* window load (``pl.dslice(r, ro, stride)``) — the
+  becomes a *strided* window load (``pl.ds(r, ro, stride)``) — the
   crossbar skips feature columns instead of the ALUs doing extra work.
 
 Grid ``(B, m_tiles, N)``: the whole batch is dispatched by one kernel
@@ -69,11 +69,9 @@ def _smm_conv_kernel(x_ref, deltas_ref, entries_ref, o_ref, acc_ref, p_ref,
         m_loc = entries_ref[0, 0, l, 1]
         r = entries_ref[0, 0, l, 2]
         c = entries_ref[0, 0, l, 3]
-        window = pl.load(p_ref, (pl.dslice(u, 1), pl.dslice(r, ro, stride),
-                                 pl.dslice(c, co, stride)))
-        cur = pl.load(acc_ref, (pl.dslice(m_loc, 1), slice(None), slice(None)))
-        pl.store(acc_ref, (pl.dslice(m_loc, 1), slice(None), slice(None)),
-                 cur + window)
+        window = p_ref[pl.ds(u, 1), pl.ds(r, ro, stride),
+                       pl.ds(c, co, stride)]
+        acc_ref[pl.ds(m_loc, 1), :, :] += window
         return 0
 
     jax.lax.fori_loop(0, l_max, ape, 0)
@@ -87,7 +85,7 @@ def _smm_conv_kernel(x_ref, deltas_ref, entries_ref, o_ref, acc_ref, p_ref,
                    static_argnames=("t_m", "ro", "co", "stride", "interpret"))
 def smm_conv_pallas(x: jax.Array, deltas: jax.Array, entries: jax.Array,
                     *, t_m: int, ro: int, co: int, stride: int = 1,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """Batched SMM convolution: ``x`` (B, N, RI, CI) → (B, m_tiles·t_m,
     RO, CO).  One compiled kernel call covers the whole batch."""
     b, n_in, ri, ci = x.shape

@@ -22,6 +22,15 @@ KERNEL_CAPS = {
                    "interpret mode off-TPU)",
 }
 
+# What the v5e compiler answers for this kernel at a VGG16 layer: the
+# per-(tile, channel) delta and entry blocks are scalar tables, not
+# (8, 128)-tiled vectors.  The backend reports this on a TPU.
+TPU_REFUSAL = (
+    "Mosaic refuses the smm_conv kernel: its (1, 1, U+1) delta block "
+    "breaks the rule that 'the last two dimensions of your block shape "
+    "are divisible by 8 and 128 respectively, or be equal to the "
+    "respective dimensions of the overall array'")
+
 
 def pack_smm_operands(code: LayerCode, n_in: int
                       ) -> tuple[np.ndarray, np.ndarray, dict]:
@@ -70,10 +79,9 @@ def smm_conv_batched(x: jax.Array, code: LayerCode, *, stride: int = 1,
     engine caches them per layer; otherwise they are packed here.
 
     ``stride`` is routed into the kernel as strided crossbar window loads.
-    Should a backend reject that lowering (Pallas cannot express strided
-    dynamic slices everywhere), the call falls back to the reference SMM
-    implementation (:func:`repro.core.smm.conv2d_smm_batched` — bit-exact,
-    slower).
+    Off-TPU the kernel runs in interpret mode; Mosaic refuses it
+    (:data:`TPU_REFUSAL`), so the ``smm_kernel`` backend is not offered
+    on a TPU.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -87,15 +95,9 @@ def smm_conv_batched(x: jax.Array, code: LayerCode, *, stride: int = 1,
         deltas, entries = jnp.asarray(deltas), jnp.asarray(entries)
     else:
         deltas, entries, meta = operands
-    try:
-        y = smm_conv_pallas(jnp.asarray(x, jnp.float32), deltas, entries,
-                            t_m=meta["t_m"], ro=ro, co=co, stride=stride,
-                            interpret=interpret)
-    except NotImplementedError:
-        from repro.core.smm import conv2d_smm_batched
-        y = jnp.asarray(conv2d_smm_batched(
-            np.rint(np.asarray(x)).astype(np.int64), code, stride),
-            jnp.float32)
+    y = smm_conv_pallas(jnp.asarray(x, jnp.float32), deltas, entries,
+                        t_m=meta["t_m"], ro=ro, co=co, stride=stride,
+                        interpret=interpret)
     return y[:, : code.shape[0]]
 
 

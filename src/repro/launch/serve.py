@@ -29,21 +29,20 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.api as codr
-from repro.configs import get_config, smoke_variant
+from repro.configs import ModelConfig, get_config, smoke_variant
 from repro.core.serving import codr_serving_stats
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 
 
-def run_serve(*, arch: str = "qwen2.5-3b", batch: int = 4,
-              prompt_len: int = 32, gen_len: int = 32, use_codr: bool = False,
+def run_serve(cfg: ModelConfig, *, batch: int = 4, prompt_len: int = 32,
+              gen_len: int = 32, use_codr: bool = False,
               codr_unique: int = 16, codr_backend: str = "codr_matmul",
               verbose: bool = True) -> dict:
-    """One serving run: prefill + greedy decode on the smoke variant of
-    ``arch``.  Returns a metrics dict (timings, generated tokens, and —
-    under ``use_codr`` — the measured packed-representation HBM bytes).
-    Importable so tests, benchmarks, and CI drive the same path as the
-    CLI."""
-    cfg = smoke_variant(get_config(arch))
+    """One serving run of ``cfg``: prefill + greedy decode.  Returns a
+    metrics dict (timings, generated tokens, and — under ``use_codr`` —
+    the measured packed-representation HBM bytes).  Importable so tests,
+    benchmarks, and CI drive the same path as the CLI."""
     api = get_model(cfg)
     key = jax.random.PRNGKey(0)
     params = api.init_params(key, cfg)
@@ -125,7 +124,7 @@ def run_serve(*, arch: str = "qwen2.5-3b", batch: int = 4,
             print("sample generation (first row):", gen[0][:16])
 
     result = {
-        "arch": arch, "family": cfg.family, "gen": gen,
+        "arch": cfg.name, "family": cfg.family, "gen": gen,
         "prefill_s": t_prefill, "decode_s": t_decode,
         "n_decode_steps": n_steps,
         "ms_per_tok": ms_per_tok,
@@ -160,7 +159,7 @@ def run_serve(*, arch: str = "qwen2.5-3b", batch: int = 4,
     return result
 
 
-def run_serve_continuous(*, arch: str = "qwen2.5-3b", n_requests: int = 4,
+def run_serve_continuous(cfg: ModelConfig, *, n_requests: int = 4,
                          n_slots: int = 4, prompt_len: int = 8,
                          gen_len: int = 8, max_len: int = 64,
                          use_codr: bool = False, codr_unique: int = 16,
@@ -171,8 +170,8 @@ def run_serve_continuous(*, arch: str = "qwen2.5-3b", n_requests: int = 4,
                          kv_page_size: int | None = None,
                          packed_ckpt: str | None = None,
                          verbose: bool = True) -> dict:
-    """Continuous-batching serving run: ``n_requests`` mixed-length
-    prompts streamed through a :class:`repro.core.batching
+    """Continuous-batching serving run of ``cfg``: ``n_requests``
+    mixed-length prompts streamed through a :class:`repro.core.batching
     .ContinuousBatcher` slot pool.  With ``check=True`` every streamed
     output is asserted bit-identical to the sequential solo-decode
     reference on the same params (the CI smoke contract); lossy KV
@@ -194,7 +193,6 @@ def run_serve_continuous(*, arch: str = "qwen2.5-3b", n_requests: int = 4,
     ``--chaos <seed> --check`` asserts in CI."""
     from repro.core.batching import ContinuousBatcher
 
-    cfg = smoke_variant(get_config(arch))
     api = get_model(cfg)
     key = jax.random.PRNGKey(seed)
 
@@ -206,7 +204,7 @@ def run_serve_continuous(*, arch: str = "qwen2.5-3b", n_requests: int = 4,
         kv_page_size = 4 if max_len <= 128 else 16
 
     compiled = None
-    boot_s = None
+    boot_s = encode_s = None
     if packed_ckpt is not None:
         import os
         if not os.path.exists(packed_ckpt):
@@ -233,12 +231,15 @@ def run_serve_continuous(*, arch: str = "qwen2.5-3b", n_requests: int = 4,
     else:
         params = api.init_params(key, cfg)
         if use_codr:
+            t0 = time.monotonic()
             compiled = codr.compile_params(
                 params, codr.EncodeConfig(n_unique=codr_unique),
                 backend=codr_backend)
+            encode_s = time.monotonic() - t0
             params = compiled.params
             if verbose:
                 print(compiled.summary())
+                print(f"encode (set-up, host): {encode_s:.2f} s")
 
     rng = np.random.default_rng(seed)
     # mixed prompt lengths around prompt_len: the join-on-prefill path
@@ -347,7 +348,7 @@ def run_serve_continuous(*, arch: str = "qwen2.5-3b", n_requests: int = 4,
                      if check_dev is not None else " (bit-identical)"))
 
     return {
-        "arch": arch, "n_requests": n_requests, "n_slots": n_slots,
+        "arch": cfg.name, "n_requests": n_requests, "n_slots": n_slots,
         "prompt_lens": lens, "gen": streamed, "total_s": t_total,
         "tokens_per_s": toks_per_s, "steps_run": batcher.steps_run,
         "prefills_run": batcher.prefills_run,
@@ -358,8 +359,9 @@ def run_serve_continuous(*, arch: str = "qwen2.5-3b", n_requests: int = 4,
                          else None),
         "worker_restarts": batcher.worker_restarts,
         "kv_dtype": kv_dtype, "kv_page_size": kv_page_size,
-        "kv_bytes": kv_bytes, "boot_s": boot_s,
+        "kv_bytes": kv_bytes, "boot_s": boot_s, "encode_s": encode_s,
         "packed_ckpt": packed_ckpt, "check_dev": check_dev,
+        "compiled": compiled, "batcher": batcher,
     }
 
 
@@ -409,19 +411,21 @@ def main() -> None:
                     help="tokens per KV page; enables the paged pool "
                          "for bf16 too (--continuous)")
     args = ap.parse_args()
+    enable_compile_cache()
+    cfg = smoke_variant(get_config(args.arch))
     packed_ckpt = args.packed_ckpt
     if packed_ckpt == "":
         packed_ckpt = f"/tmp/codr_packed_{args.arch.replace('/', '_')}.codr"
     if args.continuous:
         run_serve_continuous(
-            arch=args.arch, n_requests=args.requests, n_slots=args.slots,
+            cfg, n_requests=args.requests, n_slots=args.slots,
             prompt_len=args.prompt_len, gen_len=args.gen_len,
             use_codr=args.codr, codr_unique=args.codr_unique,
             codr_backend=args.codr_backend, check=args.check,
             chaos_seed=args.chaos, kv_dtype=args.kv_dtype,
             kv_page_size=args.kv_page_size, packed_ckpt=packed_ckpt)
     else:
-        run_serve(arch=args.arch, batch=args.batch,
+        run_serve(cfg, batch=args.batch,
                   prompt_len=args.prompt_len, gen_len=args.gen_len,
                   use_codr=args.codr, codr_unique=args.codr_unique,
                   codr_backend=args.codr_backend)

@@ -9,10 +9,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-try:                                   # jax >= 0.6 exports it at top level
-    from jax import shard_map
-except ImportError:                    # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import cache as cache_lib

@@ -138,6 +138,30 @@ def test_exception_propagates_to_failed_batch_only(compiled, rng):
     server.stop_async()
 
 
+def test_failed_transfer_fails_its_batch_not_the_device(compiled, rng,
+                                                        monkeypatch):
+    """A failed host→device transfer is not replaced by the host array:
+    exactly that batch's futures fail with it, and the loop serves on."""
+    import jax
+    real_put = jax.device_put
+
+    def put(x, *args, **kwargs):
+        if isinstance(x, np.ndarray) and (x == 12345.0).any():
+            raise RuntimeError("transfer failed")
+        return real_put(x, *args, **kwargs)
+
+    monkeypatch.setattr(jax, "device_put", put)
+    server = compiled.serve(max_batch=2, flush_deadline_s=0.02)
+    fut_bad = server.submit_async(np.full((9, 9, 3), 12345.0, np.float32))
+    with pytest.raises(RuntimeError, match="transfer failed"):
+        fut_bad.result(timeout=120)
+    good = rng.normal(size=(9, 9, 3)).astype(np.float32)
+    fut_good = server.submit_async(good)
+    ref = np.asarray(compiled.run(good[None]))[0]
+    np.testing.assert_array_equal(fut_good.result(timeout=120), ref)
+    server.stop_async()
+
+
 def test_stop_drain_false_cancels_and_restart_works(compiled, rng):
     server = compiled.serve(max_batch=64, flush_deadline_s=3600.0)
     x = rng.normal(size=(9, 9, 3)).astype(np.float32)

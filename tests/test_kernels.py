@@ -138,6 +138,16 @@ def test_smm_conv_all_zero_layer(rng):
     assert float(jnp.abs(got).max()) == 0.0
 
 
+def test_smm_kernel_backend_refused_on_tpu(monkeypatch):
+    """Mosaic refuses the smm_conv kernel, so on a TPU the backend says
+    so at compile time instead of falling back to interpret mode."""
+    import repro.api as codr
+    spec = codr.ModelSpec.from_paper_cnn("vgg16", n_conv=1, ri=8, ci=8)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="Mosaic refuses the smm_conv"):
+        codr.compile(spec, backend="smm_kernel")
+
+
 # ---------------------------------------------------------------------------
 # flash_attention (fused production kernel — EXPERIMENTS §Perf Pair 2 fix)
 # ---------------------------------------------------------------------------

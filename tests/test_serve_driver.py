@@ -11,7 +11,8 @@ from repro.configs import get_config, smoke_variant
 from repro.launch.serve import run_serve, run_serve_continuous
 from repro.models import get_model
 
-ENCDEC = "seamless-m4t-medium"
+ENCDEC = smoke_variant(get_config("seamless-m4t-medium"))
+QWEN = smoke_variant(get_config("qwen2.5-3b"))
 
 
 def test_serve_encdec_pads_self_cache_and_generates():
@@ -19,7 +20,7 @@ def test_serve_encdec_pads_self_cache_and_generates():
     self-attention KV is padded out to prompt+gen length (the old path
     left it at prompt length behind dead `if False` code and replayed
     against a zeroed cross cache)."""
-    res = run_serve(arch=ENCDEC, batch=2, prompt_len=4, gen_len=3,
+    res = run_serve(ENCDEC, batch=2, prompt_len=4, gen_len=3,
                     verbose=False)
     assert res["family"] == "encdec"
     assert res["gen"].shape == (2, 3)
@@ -28,7 +29,7 @@ def test_serve_encdec_pads_self_cache_and_generates():
 
 
 def test_serve_encdec_gen_len_zero():
-    res = run_serve(arch=ENCDEC, batch=1, prompt_len=4, gen_len=0,
+    res = run_serve(ENCDEC, batch=1, prompt_len=4, gen_len=0,
                     verbose=False)
     assert res["gen"].shape == (1, 0)
     assert res["cache_self_len"] == 4          # nothing to pad
@@ -44,7 +45,7 @@ def test_encdec_decode_from_padded_prefill_cache_matches_prefill(key):
     common.DEFAULT_DTYPE = jnp.float32
     encdec_mod.DEFAULT_DTYPE = jnp.float32
     try:
-        cfg = smoke_variant(get_config(ENCDEC))
+        cfg = ENCDEC
         cfg = dataclasses.replace(cfg, remat=False)
         api = get_model(cfg)
         params = api.init_params(key, cfg)
@@ -73,7 +74,7 @@ def test_encdec_decode_from_padded_prefill_cache_matches_prefill(key):
 def test_serve_codr_lm_decode_fused(backend):
     """The acceptance path: an repro.models LM served end-to-end from
     the packed representation, HBM bytes measured on the pack."""
-    res = run_serve(arch="qwen2.5-3b", batch=2, prompt_len=4, gen_len=3,
+    res = run_serve(QWEN, batch=2, prompt_len=4, gen_len=3,
                     use_codr=True, codr_backend=backend, verbose=False)
     assert res["gen"].shape == (2, 3)
     assert res["backend"] == backend
@@ -82,7 +83,7 @@ def test_serve_codr_lm_decode_fused(backend):
 
 
 def test_serve_codr_encdec():
-    res = run_serve(arch=ENCDEC, batch=1, prompt_len=4, gen_len=2,
+    res = run_serve(ENCDEC, batch=1, prompt_len=4, gen_len=2,
                     use_codr=True, codr_backend="tiled", verbose=False)
     assert res["gen"].shape == (1, 2)
     assert res["hbm_bytes"] > 0
@@ -93,7 +94,7 @@ def test_serve_continuous_checked():
     mixed-length requests streamed off the slot pool, every output
     asserted bit-identical to the sequential reference (check=True
     raises on any divergence)."""
-    res = run_serve_continuous(arch="qwen2.5-3b", n_requests=4, n_slots=2,
+    res = run_serve_continuous(QWEN, n_requests=4, n_slots=2,
                                prompt_len=4, gen_len=3, check=True,
                                verbose=False)
     assert res["checked"] == 4
@@ -111,7 +112,7 @@ def test_serve_continuous_packed_ckpt_int8(tmp_path):
     artifact, not the RNG, carries the weights)."""
     import os
     path = str(tmp_path / "ck.codr")
-    res = run_serve_continuous(arch="qwen2.5-3b", n_requests=3, n_slots=2,
+    res = run_serve_continuous(QWEN, n_requests=3, n_slots=2,
                                prompt_len=4, gen_len=3, check=True,
                                packed_ckpt=path, verbose=False)
     assert os.path.isdir(path)
@@ -120,7 +121,7 @@ def test_serve_continuous_packed_ckpt_int8(tmp_path):
     assert res["kv_page_size"] == 4
     assert res["boot_s"] is not None
     assert res["kv_bytes"] > 0
-    res2 = run_serve_continuous(arch="qwen2.5-3b", n_requests=3, n_slots=2,
+    res2 = run_serve_continuous(QWEN, n_requests=3, n_slots=2,
                                 prompt_len=4, gen_len=3, check=True,
                                 packed_ckpt=path, verbose=False)
     assert res2["gen"] == res["gen"]
@@ -129,10 +130,10 @@ def test_serve_continuous_packed_ckpt_int8(tmp_path):
 def test_serve_continuous_bf16_paged_matches_dense(tmp_path):
     """kv_dtype=bf16 with a page size is the escape hatch: identical
     streamed tokens to the dense-pool run, same params."""
-    kw = dict(arch="qwen2.5-3b", n_requests=3, n_slots=2,
+    kw = dict(n_requests=3, n_slots=2,
               prompt_len=4, gen_len=3, verbose=False)
-    dense = run_serve_continuous(**kw)
-    paged = run_serve_continuous(kv_dtype="bf16", kv_page_size=4,
+    dense = run_serve_continuous(QWEN, **kw)
+    paged = run_serve_continuous(QWEN, kv_dtype="bf16", kv_page_size=4,
                                  check=True, **kw)
     assert paged["gen"] == dense["gen"]
     assert paged["checked"] == 3

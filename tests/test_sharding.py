@@ -7,6 +7,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs import get_config, smoke_variant
 from repro.configs.base import ShapeConfig
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import (batch_spec, build_cell, input_specs,
                                 serve_param_fsdp)
 from repro.sharding.rules import param_spec
@@ -75,7 +76,7 @@ def test_serve_fsdp_heuristic(mesh16):
 def test_pjit_train_step_on_host_mesh(key):
     """Real execution of the sharded train step on a 1×1 mesh."""
     cfg = smoke_variant(get_config("qwen2.5-3b"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     shape = ShapeConfig("tiny", 32, 2, "train")
     fn, arg_shapes, in_sh, _ = build_cell(cfg, shape, mesh)
     api_params, opt, batch_specs = arg_shapes

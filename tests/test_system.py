@@ -71,8 +71,9 @@ def test_dryrun_cell_builder_abstract(shape_name):
     """build_cell produces coherent abstract shapes/shardings on a tiny
     mesh (the 512-device path is exercised by repro.launch.dryrun)."""
     from repro.configs.base import SHAPES
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.steps import build_cell
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     shape = dataclasses.replace(SHAPES[shape_name], global_batch=2,
                                 seq_len=64)
     cfg = smoke_variant(get_config("granite-moe-1b-a400m"))
