@@ -1,8 +1,8 @@
-"""Benchmark harness — one module per paper table/figure plus the
-roofline report.  Prints ``name,us_per_call,derived`` CSV lines.
+"""Benchmark harness — one module per paper table/figure.  Prints
+``name,us_per_call,derived`` CSV lines.
 
-  python -m benchmarks.run [--only fig6|compression|fig7|fig8|kernels|
-                                   roofline|engine|decode]
+  python -m benchmarks.run [--only fig6|compression|fig7|fig8|engine|
+                                   decode]
                            [--small]
 
 ``compression`` is ``fig6`` plus the tuning-lane Pareto section (the
@@ -18,8 +18,7 @@ from __future__ import annotations
 import argparse
 import sys
 
-from benchmarks import compression, decode, energy, engine, kernels, \
-    roofline, sram_access
+from benchmarks import compression, decode, energy, engine, sram_access
 from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
@@ -27,8 +26,6 @@ SUITES = {
     "compression": compression.main,   # fig6 + tuning-lane Pareto curve
     "fig7": sram_access.main,
     "fig8": energy.main,
-    "kernels": kernels.main,
-    "roofline": roofline.main,
     "engine": engine.main,
     "decode": decode.main,
 }

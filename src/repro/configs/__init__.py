@@ -33,8 +33,8 @@ def get_config(arch: str) -> ModelConfig:
 
 
 def applicable_shapes(cfg: ModelConfig) -> dict[str, str]:
-    """shape_name → 'run' | reason-to-skip (recorded in the roofline
-    table; see docs/DESIGN.md §4)."""
+    """shape_name → 'run' | reason-to-skip (recorded by the dry run;
+    see docs/DESIGN.md §4)."""
     out = {}
     for name, shp in SHAPES.items():
         if name == "long_500k" and not cfg.sub_quadratic:

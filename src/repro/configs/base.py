@@ -56,8 +56,7 @@ class ModelConfig:
     remat: bool = True
     sub_quadratic: bool = False    # can run long_500k
     mamba_chunk: int = 256
-    # ---- perf levers (baseline = defaults; measured via the roofline
-    # ---- report, see benchmarks/roofline.py) ----
+    # ---- perf levers (baseline = defaults) ----
     decode_attn: str = "naive"     # "dist" = sequence-parallel softmax
     moe_decode_2d: bool = False    # 2-D expert sharding for decode
     attn_f32: bool = True          # False = bf16 score/accum buffers

@@ -72,6 +72,7 @@ from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as _P
 
 from repro.core import smm, ucr
+from repro.runtime import tracing
 
 __all__ = [
     "Backend", "BackendCaps", "available_backends", "get_backend",
@@ -340,9 +341,17 @@ class TiledBackend(Backend):
         # whole-model jitted chain, cached on the model — XLA fuses across
         # layer boundaries; repeat same-shape requests re-trace nothing
         if model._run_tiled is None:
-            model._run_tiled = jax.jit(
-                lambda x: model._chain(x, lambda l, xx: l(xx)))
-        return model._run_tiled(jnp.asarray(batch, jnp.float32))
+            def cnn_chain(x):
+                return model._chain(x, lambda l, xx: l(xx))
+            model._run_tiled = jax.jit(cnn_chain)
+        with tracing.span("model.put", batch=len(batch)):
+            x = jnp.asarray(batch, jnp.float32)
+            if tracing.enabled():
+                # traced: the span ends when the input has landed on the
+                # device, so it covers the device's wait for the transfer
+                x.block_until_ready()
+        with tracing.span("model.dispatch"):
+            return model._run_tiled(x)
 
 
 class SmmBackend(Backend):

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.serving import AsyncWorkerLoop
+from repro.runtime import tracing
 
 _DONE = object()                    # stream sentinel: generation finished
 
@@ -128,6 +129,7 @@ class _Pending:
     eos_id: int | None
     handle: GenerationHandle
     deadline: float | None = None   # absolute monotonic deadline
+    queued_ns: int | None = None    # submit time, when spans are recorded
 
 
 class ContinuousBatcher(AsyncWorkerLoop):
@@ -192,11 +194,18 @@ class ContinuousBatcher(AsyncWorkerLoop):
         self._params = getattr(params, "params", params)
         self._api = get_model(cfg)
         self._cache_mod = cache_mod
-        self._prefill_fn = jax.jit(
-            lambda p, t: self._api.prefill(p, {"tokens": t}, cfg))
-        self._step_fn = jax.jit(
-            lambda p, pool, tok, pos: self._api.decode_step(
-                p, pool, tok, pos, cfg))
+        api = self._api
+
+        # named for what they run: the trace shows jit_prefill(...),
+        # jit_decode_step(...) and jit_write_slot(...)
+        def prefill(p, t):
+            return api.prefill(p, {"tokens": t}, cfg)
+
+        def decode_step(p, pool, tok, pos):
+            return api.decode_step(p, pool, tok, pos, cfg)
+
+        self._prefill_fn = jax.jit(prefill)
+        self._step_fn = jax.jit(decode_step)
         if kv_page_size is not None:
             # paged KV: pool of fixed-size pages + per-slot page tables
             # (docs/DESIGN.md §2.2).  The page table lives host-side
@@ -211,9 +220,11 @@ class ContinuousBatcher(AsyncWorkerLoop):
             self._slot_pages: list[list[int] | None] = [None] * n_slots  # guarded-by: _cv
             self._kv_table = np.zeros((n_slots, self._paged.max_pages),  # guarded-by: _cv
                                       np.int32)
-            self._write_fn = jax.jit(
-                lambda pool, c, slot, pages: cache_mod.write_slot_paged(
-                    pool, c, slot, pages))
+
+            def write_slot(pool, c, slot, pages):
+                return cache_mod.write_slot_paged(pool, c, slot, pages)
+
+            self._write_fn = jax.jit(write_slot)
             self._pool = self._api.init_cache(cfg, n_slots, max_len,
                                               paged=self._paged)
         else:
@@ -226,9 +237,11 @@ class ContinuousBatcher(AsyncWorkerLoop):
                                                             max_len)),
                 jax.eval_shape(lambda: self._api.init_cache(cfg, 2,
                                                             max_len)))
-            self._write_fn = jax.jit(
-                lambda pool, c, slot: cache_mod.write_slot(
-                    pool, c, slot, self._axes))
+
+            def write_slot(pool, c, slot):
+                return cache_mod.write_slot(pool, c, slot, self._axes)
+
+            self._write_fn = jax.jit(write_slot)
             self._pool = self._api.init_cache(cfg, n_slots, max_len)
         self._slots: list[_Slot | None] = [None] * n_slots  # guarded-by: _cv
         self._pending: list[_Pending] = []  # guarded-by: _cv
@@ -279,6 +292,7 @@ class ContinuousBatcher(AsyncWorkerLoop):
             if deadline_s <= 0:
                 raise ValueError("deadline_s must be > 0 (or None)")
             deadline = time.monotonic() + deadline_s
+        queued = tracing.now()
         with self._cv:
             if self._stopping:
                 raise RuntimeError(
@@ -287,6 +301,11 @@ class ContinuousBatcher(AsyncWorkerLoop):
             if (self.max_pending is not None
                     and len(self._pending) >= self.max_pending):
                 self.requests_shed += 1
+                if queued is not None:
+                    tracing.record("batcher.queue", queued,
+                                   time.monotonic_ns(),
+                                   prompt_len=int(prompt.size),
+                                   outcome="shed")
                 from repro.runtime.resilience import RejectedError
                 raise RejectedError(
                     f"admission queue full ({len(self._pending)}/"
@@ -298,7 +317,7 @@ class ContinuousBatcher(AsyncWorkerLoop):
             self._pending.append(_Pending(
                 prompt, max_new_tokens,
                 self.eos_id if eos_id is None else eos_id, handle,
-                deadline))
+                deadline, queued))
             if self._worker is None or not self._worker.is_alive():
                 self._start_locked()
             self._cv.notify_all()
@@ -381,6 +400,14 @@ class ContinuousBatcher(AsyncWorkerLoop):
         from repro.runtime import resilience
         return resilience.retry_call(fn, policy=pol, supervisor=sup)
 
+    @staticmethod
+    def _queued(req: _Pending, **attrs) -> None:
+        """The ``batcher.queue`` span of ``req``: submit to now."""
+        if req.queued_ns is not None:
+            tracing.record("batcher.queue", req.queued_ns,
+                           time.monotonic_ns(), rid=req.handle.rid,
+                           prompt_len=int(req.prompt.size), **attrs)
+
     def _loop(self) -> None:
         with self._cv:
             self._abort_active = False
@@ -436,6 +463,7 @@ class ContinuousBatcher(AsyncWorkerLoop):
                         # expired while queued: never burn a prefill on
                         # a request nobody is waiting for
                         self.requests_expired += 1
+                        self._queued(req, outcome="expired")
                         from repro.runtime.resilience import \
                             DeadlineExceeded
                         req.handle._fail(DeadlineExceeded(
@@ -446,6 +474,7 @@ class ContinuousBatcher(AsyncWorkerLoop):
                     # budget — all-or-nothing, so a request can never
                     # run out of pages mid-stream) under the lock;
                     # prefill happens outside it
+                    self._queued(req)
                     kv_row = None
                     if self._paged is not None:
                         need = self._paged.pages_for(
@@ -489,99 +518,118 @@ class ContinuousBatcher(AsyncWorkerLoop):
                                             jnp.int32(slot_idx))
             return np.asarray(logits, np.float32).reshape(-1)
 
-        try:
-            row = self._guarded(_attempt)
-        except Exception as e:      # noqa: BLE001 — lands on the handle
+        with tracing.span("batcher.prefill", rid=req.handle.rid,
+                          prompt_len=int(req.prompt.size)) as sp:
+            try:
+                row = self._guarded(_attempt)
+            except Exception as e:  # noqa: BLE001 — lands on the handle
+                sp.set(error=type(e).__name__)
+                with self._cv:
+                    self._slots[slot_idx] = None
+                    self._release_pages_locked(slot_idx)
+                req.handle._fail(e)
+                return
+            tok = int(np.argmax(row))
             with self._cv:
-                self._slots[slot_idx] = None
-                self._release_pages_locked(slot_idx)
-            req.handle._fail(e)
-            return
-        tok = int(np.argmax(row))
-        with self._cv:
-            slot = self._slots[slot_idx]
-            slot.last_tok = tok
-            slot.pos = int(req.prompt.size)
-            slot.n_gen = 1
-            self.prefills_run += 1
-            self._last_admit_t = time.monotonic()
-            n_active = sum(s is not None for s in self._slots)
-            self.peak_active = max(self.peak_active, n_active)
-        req.handle._emit(tok, row if self.record_logits else None)
+                slot = self._slots[slot_idx]
+                slot.last_tok = tok
+                slot.pos = int(req.prompt.size)
+                slot.n_gen = 1
+                self.prefills_run += 1
+                self._last_admit_t = time.monotonic()
+                n_active = sum(s is not None for s in self._slots)
+                self.peak_active = max(self.peak_active, n_active)
+            req.handle._emit(tok, row if self.record_logits else None)
         self._maybe_retire(slot_idx, tok)
 
     def _decode_active(self) -> None:
-        with self._cv:
-            # deadline sweep: a slot whose request expired mid-stream
-            # retires NOW — it must not hold a slot for tokens nobody
-            # will read
-            expired = [(i, s) for i, s in enumerate(self._slots)
-                       if s is not None and s.deadline is not None
-                       and time.monotonic() >= s.deadline]
-            for i, s in expired:
-                self._slots[i] = None
-                self._release_pages_locked(i)
-                self.requests_finished += 1
-                self.requests_expired += 1
-            if expired:
-                from repro.runtime.resilience import DeadlineExceeded
-                for _, s in expired:
-                    s.handle._fail(DeadlineExceeded(
-                        f"deadline expired after {s.n_gen} token(s)"),
-                        reason="deadline")
-                self._cv.notify_all()
-            active = [(i, s) for i, s in enumerate(self._slots)
-                      if s is not None]
-            kv_table = (self._kv_table.copy() if self._paged is not None
-                        else None)
-        if not active:
-            return
-        toks = np.zeros((self.n_slots,), np.int32)
-        poss = np.zeros((self.n_slots,), np.int32)
-        for i, s in active:
-            toks[i] = s.last_tok
-            poss[i] = s.pos
-        if kv_table is not None:
-            # push the authoritative host page table into the device
-            # pool: retired slots now point at scratch, fresh admits at
-            # their reserved pages
-            self._pool = self._cache_mod.set_tables(self._pool, kv_table)
-
-        def _attempt():
-            # retry-safe: self._pool is only replaced on success, so a
-            # failed step recomputes from identical state → identical
-            # bits on the retry (the pooled step is deterministic)
-            self._fire("batcher.decode")
-            logits, pool = self._step_fn(
-                self._params, self._pool, jnp.asarray(toks),
-                jnp.asarray(poss))
-            return np.asarray(logits, np.float32), pool
-
-        t0 = time.monotonic()
-        try:
-            rows, self._pool = self._guarded(_attempt)
-        except Exception as e:      # noqa: BLE001 — exactly this batch
+        """One pooled decode step over every active slot: a
+        ``batcher.step`` span whose children are the page-table and
+        input push, the dispatch, the wait for the device, the logits
+        fetch and the host's sampling and delivery."""
+        with tracing.span("batcher.step") as step:
             with self._cv:
-                for i, s in active:
+                # deadline sweep: a slot whose request expired mid-stream
+                # retires NOW — it must not hold a slot for tokens nobody
+                # will read
+                expired = [(i, s) for i, s in enumerate(self._slots)
+                           if s is not None and s.deadline is not None
+                           and time.monotonic() >= s.deadline]
+                for i, s in expired:
                     self._slots[i] = None
                     self._release_pages_locked(i)
                     self.requests_finished += 1
-                for _, s in active:
-                    s.handle._fail(e)
-            return
-        sup = self._supervisor
-        if sup is not None:
-            sup.record_latency(time.monotonic() - t0)
-        with self._cv:
-            self.steps_run += 1
-        for i, s in active:
-            tok = int(np.argmax(rows[i]))
-            s.pos += 1
-            s.n_gen += 1
-            s.last_tok = tok
-            s.handle._emit(tok,
-                           rows[i].copy() if self.record_logits else None)
-            self._maybe_retire(i, tok)
+                    self.requests_expired += 1
+                if expired:
+                    from repro.runtime.resilience import DeadlineExceeded
+                    for _, s in expired:
+                        s.handle._fail(DeadlineExceeded(
+                            f"deadline expired after {s.n_gen} token(s)"),
+                            reason="deadline")
+                    self._cv.notify_all()
+                active = [(i, s) for i, s in enumerate(self._slots)
+                          if s is not None]
+                kv_table = (self._kv_table.copy() if self._paged is not None
+                            else None)
+            if not active:
+                step.discard()
+                return
+            step.set(n_active=len(active))
+            toks = np.zeros((self.n_slots,), np.int32)
+            poss = np.zeros((self.n_slots,), np.int32)
+            for i, s in active:
+                toks[i] = s.last_tok
+                poss[i] = s.pos
+
+            def _attempt():
+                # retry-safe: self._pool is only replaced on success, so a
+                # failed step recomputes from identical state → identical
+                # bits on the retry (the pooled step is deterministic)
+                pool = self._pool
+                with tracing.span("batcher.push"):
+                    if kv_table is not None:
+                        # push the authoritative host page table into the
+                        # device pool: retired slots now point at
+                        # scratch, fresh admits at their reserved pages
+                        pool = self._cache_mod.set_tables(pool, kv_table)
+                    toks_d, poss_d = jnp.asarray(toks), jnp.asarray(poss)
+                with tracing.span("batcher.dispatch"):
+                    self._fire("batcher.decode")
+                    logits, pool = self._step_fn(self._params, pool,
+                                                 toks_d, poss_d)
+                with tracing.span("batcher.wait"):
+                    jax.block_until_ready(logits)
+                with tracing.span("batcher.fetch"):
+                    rows = np.asarray(logits, np.float32)
+                return rows, pool
+
+            t0 = time.monotonic()
+            try:
+                rows, self._pool = self._guarded(_attempt)
+            except Exception as e:  # noqa: BLE001 — exactly this batch
+                step.set(error=type(e).__name__)
+                with self._cv:
+                    for i, s in active:
+                        self._slots[i] = None
+                        self._release_pages_locked(i)
+                        self.requests_finished += 1
+                    for _, s in active:
+                        s.handle._fail(e)
+                return
+            sup = self._supervisor
+            if sup is not None:
+                sup.record_latency(time.monotonic() - t0)
+            with self._cv:
+                self.steps_run += 1
+            with tracing.span("batcher.sample"):
+                for i, s in active:
+                    tok = int(np.argmax(rows[i]))
+                    s.pos += 1
+                    s.n_gen += 1
+                    s.last_tok = tok
+                    s.handle._emit(tok, rows[i].copy() if self.record_logits
+                                   else None)
+                    self._maybe_retire(i, tok)
 
     def _maybe_retire(self, slot_idx: int, tok: int) -> None:
         with self._cv:

@@ -1,7 +1,6 @@
 """Pallas TPU kernel: fused flash attention.
 
-This is the production fix for the dominant memory term the roofline
-report surfaces (``benchmarks/roofline.py``; methodology in
+This is the fix for attention's dominant memory term (methodology in
 ``docs/DESIGN.md`` §6): the XLA-compiled attention materializes every
 (q_block × kv_block) score tile in HBM (B·H·S² traffic); the fused
 kernel keeps score tiles, the online-softmax stats, and the output

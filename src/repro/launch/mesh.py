@@ -27,8 +27,3 @@ def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU smoke)."""
     return _auto_mesh((data, model), ("data", "model"))
 
-
-# TPU v5e hardware constants used by the roofline (per chip)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW = 50e9                   # B/s per link
