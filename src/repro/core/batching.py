@@ -132,6 +132,20 @@ class _Pending:
     queued_ns: int | None = None    # submit time, when spans are recorded
 
 
+def _codr_row_blocks(params):
+    """``codr_matmul``'s ``row_blocks`` (how many times an ``m``-row call
+    decodes each packed weight block) when a projection of ``params``
+    runs on that kernel, else None."""
+    from repro.core.codr_linear import PackedLinear
+    leaves = jax.tree_util.tree_leaves(
+        params, is_leaf=lambda leaf: isinstance(leaf, PackedLinear))
+    if not any(isinstance(leaf, PackedLinear)
+               and leaf.backend == "codr_matmul" for leaf in leaves):
+        return None
+    from repro.kernels.codr_matmul.kernel import row_blocks
+    return row_blocks
+
+
 class ContinuousBatcher(AsyncWorkerLoop):
     """Slot-pooled continuous-batching decode loop over an LM.
 
@@ -192,6 +206,7 @@ class ContinuousBatcher(AsyncWorkerLoop):
         self.max_pending = max_pending      # bounded admission (None=∞)
         # CompiledParams duck-typing: serve from its packed pytree
         self._params = getattr(params, "params", params)
+        self._row_blocks = _codr_row_blocks(self._params)
         self._api = get_model(cfg)
         self._cache_mod = cache_mod
         api = self._api
@@ -518,8 +533,10 @@ class ContinuousBatcher(AsyncWorkerLoop):
                                             jnp.int32(slot_idx))
             return np.asarray(logits, np.float32).reshape(-1)
 
+        passes = ({} if self._row_blocks is None else
+                  {"weight_passes": self._row_blocks(int(req.prompt.size))})
         with tracing.span("batcher.prefill", rid=req.handle.rid,
-                          prompt_len=int(req.prompt.size)) as sp:
+                          prompt_len=int(req.prompt.size), **passes) as sp:
             try:
                 row = self._guarded(_attempt)
             except Exception as e:  # noqa: BLE001 — lands on the handle

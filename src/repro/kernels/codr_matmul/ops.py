@@ -27,8 +27,8 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def codr_matmul(x: jax.Array, w: PackedWeight, *, bm: int = 128,
-                bn: int = 2048, bk: int = 512,
+def codr_matmul(x: jax.Array, w: PackedWeight, *, bm: int | None = None,
+                bn: int | None = None, bk: int | None = None,
                 interpret: bool | None = None) -> jax.Array:
     """``y = x @ decode(w)`` with the decode fused into the matmul tiles."""
     if interpret is None:

@@ -8,7 +8,7 @@ import pytest
 from repro.core import ucr
 from repro.core.codr_linear import pack_unique, unpack_unique
 from repro.core.serving import restrict_unique
-from repro.kernels.codr_matmul import codr_matmul
+from repro.kernels.codr_matmul import codr_matmul, kernel
 from repro.kernels.codr_matmul.ref import codr_matmul_ref
 from repro.kernels.smm_conv import smm_conv, smm_conv_ref
 
@@ -62,6 +62,51 @@ def test_codr_matmul_block_sweep(blocks, rng):
                          bits=pw.bits, n=256)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                rtol=2e-3, atol=2e-4)
+
+
+@pytest.mark.parametrize("m", [256, 1024, 1100])
+def test_codr_matmul_default_blocks_prompt_rows(m, rng):
+    """Prompt-sized calls on the default blocks: one row block up to
+    ``ROW_CAP`` rows, equal row blocks above (1100 rows: two)."""
+    pw = _packed(rng, 256, 512, 16)
+    x = jnp.asarray(rng.normal(size=(m, 256)).astype(np.float32))
+    y = codr_matmul(x, pw, interpret=True)
+    yr = codr_matmul_ref(x, pw.packed, pw.table, pw.scale.reshape(-1),
+                         bits=pw.bits, n=512)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
+                               rtol=2e-3, atol=2e-4)
+    assert kernel.row_blocks(m) == (1 if m <= kernel.ROW_CAP else 2)
+
+
+# qwen2.5-3b projections (K, N) at 4 bits -> the pooled decode step's blocks
+QWEN_DECODE_BLOCKS = {(2048, 2048): (256, 512), (2048, 256): (32, 512),
+                      (2048, 11008): (256, 512), (11008, 2048): (256, 256)}
+
+
+@pytest.mark.parametrize("m", [8, 32, 64, 128])
+def test_codr_matmul_decode_step_blocks_pinned(m):
+    """Up to 128 rows the default blocks are (m, 2048 columns, 512
+    contraction rows) fitted to the tiling, as before the row rule."""
+    for (k, n), (bw, bk) in QWEN_DECODE_BLOCKS.items():
+        assert kernel._blocks(m, k, n // 8, 4) == (m, bw, bk), (k, n)
+
+
+def test_codr_matmul_chat_prompts_one_pass():
+    """Every prompt length of the chat mix decodes each weight block
+    once, and its blocks fit the scoped VMEM the kernel asks for, at
+    every index width."""
+    import json
+    from pathlib import Path
+    chat = Path(__file__).resolve().parents[1] / "bench/traffic/chat.json"
+    grid = json.loads(chat.read_text())["prompt_len"]["grid"]
+    for m in grid + [kernel.ROW_CAP]:
+        assert kernel.row_blocks(m) == 1, m
+        for bits in (1, 2, 4, 8, 16):
+            for k, n in QWEN_DECODE_BLOCKS:
+                blocks = kernel._blocks(m, k, n * bits // 32, bits)
+                assert blocks[0] == m
+                assert kernel.vmem_bytes(*blocks, bits) <= \
+                    kernel.VMEM_LIMIT, (m, bits, k, n)
 
 
 def test_pack_unpack_roundtrip(rng):

@@ -52,7 +52,7 @@ def _shape(sharding, shape, dtype):
 
 
 @pytest.mark.parametrize("k,n", QWEN_PROJECTIONS)
-@pytest.mark.parametrize("m", [8, 128])
+@pytest.mark.parametrize("m", [8, 128, 256, 512, 1024])
 def test_codr_matmul_compiles_for_v5e(m, k, n, one_chip, no_compile_cache):
     from repro.kernels.codr_matmul.kernel import codr_matmul_pallas
     bits = 4

@@ -187,6 +187,35 @@ def test_batcher_spans_and_the_same_bits(lm, kv):
         sum(len(t) - 1 for t in on)
 
 
+@pytest.mark.parametrize("backend", ["codr_matmul", "tiled", None],
+                         ids=["codr_matmul", "tiled", "dense"])
+def test_batcher_prefill_weight_passes(lm, backend):
+    """A prefill on ``codr_matmul`` packs records how many times its
+    kernel calls decode each weight block, the kernel's own
+    ``row_blocks``; on other packs or dense params it records none."""
+    from repro.kernels.codr_matmul.kernel import row_blocks
+    cfg, params = lm
+    if backend is not None:
+        params = codr.compile_params(params, codr.EncodeConfig(n_unique=16),
+                                     backend=backend)
+    cb = ContinuousBatcher(params, cfg, n_slots=2, max_len=32)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (3, 9)]
+    tracing.enable()
+    _serve(cb, prompts, 1)
+    cb.stop_async()
+    tracing.disable()
+    pre = [s for s in tracing.drain() if s.name == "batcher.prefill"]
+    assert sorted(s.attrs["prompt_len"] for s in pre) == [3, 9]
+    for s in pre:
+        if backend == "codr_matmul":
+            assert s.attrs["weight_passes"] == \
+                row_blocks(s.attrs["prompt_len"]) == 1
+        else:
+            assert "weight_passes" not in s.attrs
+
+
 def test_batcher_retry_records_each_try_under_its_step(lm):
     cfg, params = lm
     cb = ContinuousBatcher(params, cfg, n_slots=2, max_len=32)
