@@ -7,6 +7,7 @@ import dataclasses
 from repro.configs.base import SHAPES, ModelConfig, ShapeConfig
 from repro.configs.command_r_plus_104b import CONFIG as _command_r
 from repro.configs.deepseek_v2_236b import CONFIG as _deepseek
+from repro.configs.deepseek_v2_lite import CONFIG as _deepseek_lite
 from repro.configs.granite_moe_1b import CONFIG as _granite
 from repro.configs.internvl2_26b import CONFIG as _internvl
 from repro.configs.jamba_v01_52b import CONFIG as _jamba
@@ -19,7 +20,7 @@ from repro.configs.xlstm_350m import CONFIG as _xlstm
 REGISTRY: dict[str, ModelConfig] = {
     c.name: c for c in [
         _qwen25, _qwen15, _command_r, _qwen3, _seamless,
-        _deepseek, _granite, _internvl, _xlstm, _jamba,
+        _deepseek, _granite, _internvl, _xlstm, _jamba, _deepseek_lite,
     ]
 }
 
@@ -66,7 +67,9 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
         remat=False,
     )
     if cfg.use_mla:
-        changes.update(q_lora_rank=32, kv_lora_rank=16, nope_head_dim=16,
+        # a config without q-LoRA keeps its plain query projection
+        changes.update(q_lora_rank=32 if cfg.q_lora_rank else 0,
+                       kv_lora_rank=16, nope_head_dim=16,
                        rope_head_dim=8, v_head_dim=16, head_dim=16)
     if cfg.n_experts:
         changes.update(n_experts=8, moe_top_k=min(cfg.moe_top_k, 4),

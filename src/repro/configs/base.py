@@ -6,6 +6,21 @@ from typing import Any
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling``, type "yarn"):
+    the rotary frequencies between the two correction dims blend the
+    plain and the ``factor``-stretched ones, cos and sin are scaled by
+    ``m(mscale) / m(mscale_all_dim)``, and the softmax scale by
+    ``m(mscale_all_dim)**2``, where ``m(a) = 0.1 * a * ln(factor) + 1``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                    # dense | moe | ssm | hybrid | encdec | vlm | audio
@@ -20,6 +35,7 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 1e4
+    rope_scaling: YarnScaling | None = None
     norm_type: str = "rmsnorm"
     act: str = "silu"
     tied_embeddings: bool = False
@@ -37,6 +53,12 @@ class ModelConfig:
     moe_d_ff: int = 0
     moe_every: int = 1             # MoE on layers with idx % moe_every == moe_offset
     moe_offset: int = 0
+    # "topk_softmax": top-k of the router logits, softmax over those k
+    # (Mixtral, Granite, Jamba); "softmax_topk": softmax over every
+    # expert, then the top k of it, not renormalised, times
+    # ``moe_routed_scale`` (DeepSeek-V2)
+    moe_gating: str = "topk_softmax"
+    moe_routed_scale: float = 1.0
     n_dense_layers: int = 0        # leading non-scanned dense layers
     # heterogeneous layer pattern — one period, scanned n_period times
     block_pattern: tuple = ("attn",)
@@ -93,6 +115,17 @@ class ModelConfig:
                 f"{self.name}: moe_every must divide the pattern period"
         return plan
 
+    def _mla_params(self) -> int:
+        """One MLA block's projections; without q-LoRA (``q_lora_rank``
+        0) the query is one ``d -> H * (dn + dr)`` projection."""
+        d, h = self.d_model, self.n_heads
+        q_out = h * (self.nope_head_dim + self.rope_head_dim)
+        q = (d * self.q_lora_rank + self.q_lora_rank * q_out
+             if self.q_lora_rank else d * q_out)
+        return q + d * (self.kv_lora_rank + self.rope_head_dim) \
+            + self.kv_lora_rank * h * (self.nope_head_dim + self.v_head_dim) \
+            + h * self.v_head_dim * d
+
     def param_count(self) -> int:
         """Analytic parameter count (embedding + blocks)."""
         d = self.d_model
@@ -104,11 +137,7 @@ class ModelConfig:
             per_layer = 0
             if kind == "attn":
                 if self.use_mla:
-                    per_layer += d * self.q_lora_rank \
-                        + self.q_lora_rank * self.n_heads * (self.nope_head_dim + self.rope_head_dim) \
-                        + d * (self.kv_lora_rank + self.rope_head_dim) \
-                        + self.kv_lora_rank * self.n_heads * (self.nope_head_dim + self.v_head_dim) \
-                        + self.n_heads * self.v_head_dim * d
+                    per_layer += self._mla_params()
                 else:
                     per_layer += d * self.head_dim * (self.n_heads + 2 * self.n_kv_heads) \
                         + self.n_heads * self.head_dim * d
@@ -132,11 +161,7 @@ class ModelConfig:
             att = d * self.head_dim * (self.n_heads + 2 * self.n_kv_heads) \
                 + self.n_heads * self.head_dim * d
             if self.use_mla:
-                att = d * self.q_lora_rank \
-                    + self.q_lora_rank * self.n_heads * (self.nope_head_dim + self.rope_head_dim) \
-                    + d * (self.kv_lora_rank + self.rope_head_dim) \
-                    + self.kv_lora_rank * self.n_heads * (self.nope_head_dim + self.v_head_dim) \
-                    + self.n_heads * self.v_head_dim * d
+                att = self._mla_params()
             total += self.n_dense_layers * (att + 3 * d * self.d_ff)
         if self.n_encoder_layers:
             att = d * self.head_dim * (self.n_heads + 2 * self.n_kv_heads) \

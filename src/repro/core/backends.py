@@ -250,6 +250,22 @@ class Backend(abc.ABC):
         gates on that flag."""
         return jnp.dot(x, w.dense().astype(x.dtype))
 
+    def grouped_matmul(self, x: jax.Array, group_sizes: jax.Array, w, *,
+                       max_group: int | None = None) -> jax.Array:
+        """Execute one packed expert stack (a ``PackedLinear`` whose pack
+        leads with the expert dim ``E``): rows of ``x (m, K)`` sorted by
+        expert, ``group_sizes[e]`` of them for expert ``e``, each times
+        its expert's dequantized matrix → ``(m, out_features)`` in
+        ``x``'s dtype.  ``models.common.grouped_linear`` routes MoE
+        expert stacks here; ``max_group`` bounds any one group.
+
+        The default decodes the whole stack and runs ``lax.ragged_dot``
+        with the dense ``grouped_linear`` numerics — the reference lane
+        (``tiled``, ``sharded``).  ``codr_matmul`` overrides it with the
+        grouped kernel, which decodes only the experts given rows."""
+        del max_group
+        return jax.lax.ragged_dot(x, w.dense().astype(x.dtype), group_sizes)
+
     def gather(self, tokens: jax.Array, w) -> jax.Array:
         """Embedding lookup on a packed vocabulary table
         (:class:`repro.core.codr_linear.PackedEmbedding`): gather the
@@ -458,6 +474,19 @@ class CodrMatmulBackend(Backend):
         x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
         y = codr_matmul(x2, w.weight)[:, : w.out_features]
         return y.reshape(*lead, w.out_features).astype(x.dtype)
+
+    def grouped_matmul(self, x, group_sizes, w, *, max_group=None):
+        """The grouped kernel (:mod:`repro.kernels.codr_gmm`): each
+        packed block of an expert that has rows is decoded in VMEM once
+        per call, an expert without rows is skipped."""
+        from repro.kernels.codr_gmm import codr_gmm
+        if w.weight.packed.ndim != 3:
+            raise ValueError(
+                "grouped_matmul executes one (E, K, N) expert stack; got "
+                f"a pack of shape {w.weight.packed.shape}")
+        y = codr_gmm(x.astype(jnp.float32), group_sizes, w.weight,
+                     max_group=max_group)[:, : w.out_features]
+        return y.astype(x.dtype)
 
     def linear(self, layer, x):
         from repro.core.codr_linear import pack_unique

@@ -30,6 +30,7 @@ restart, exception isolation) is :class:`repro.core.serving
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import queue as queue_mod
 import time
@@ -43,6 +44,7 @@ from repro.core.serving import AsyncWorkerLoop
 from repro.runtime import tracing
 
 _DONE = object()                    # stream sentinel: generation finished
+EXPERT_LOG = 8192                   # programs whose expert counts are kept
 
 
 class GenerationHandle:
@@ -132,6 +134,12 @@ class _Pending:
     queued_ns: int | None = None    # submit time, when spans are recorded
 
 
+def _outputs(out):
+    """``(logits, cache, experts touched)`` of a prefill or decode step:
+    the count only a MoE model's programs return, None for the rest."""
+    return (*out, None)[:3]
+
+
 def _codr_row_blocks(params):
     """``codr_matmul``'s ``row_blocks`` (how many times an ``m``-row call
     decodes each packed weight block) when a projection of ``params``
@@ -196,6 +204,7 @@ class ContinuousBatcher(AsyncWorkerLoop):
         super().__init__()
         from repro.models import get_model          # lazy: core → models
         from repro.models import cache as cache_mod
+        from repro.models import lm
         self.cfg = cfg
         self.n_slots = n_slots
         self.max_len = max_len
@@ -212,12 +221,17 @@ class ContinuousBatcher(AsyncWorkerLoop):
         api = self._api
 
         # named for what they run: the trace shows jit_prefill(...),
-        # jit_decode_step(...) and jit_write_slot(...)
+        # jit_decode_step(...) and jit_write_slot(...).  A MoE model's
+        # also return its count of (layer, expert) pairs given rows
+        # (``_outputs``); a dense model's programs stay as they were.
+        count = lm.has_experts(cfg)
+
         def prefill(p, t):
-            return api.prefill(p, {"tokens": t}, cfg)
+            return api.prefill(p, {"tokens": t}, cfg, count_experts=count)
 
         def decode_step(p, pool, tok, pos):
-            return api.decode_step(p, pool, tok, pos, cfg)
+            return api.decode_step(p, pool, tok, pos, cfg,
+                                   count_experts=count)
 
         self._prefill_fn = jax.jit(prefill)
         self._step_fn = jax.jit(decode_step)
@@ -270,6 +284,10 @@ class ContinuousBatcher(AsyncWorkerLoop):
         self.peak_active = 0                # guarded-by: _cv
         self.requests_shed = 0              # guarded-by: _cv
         self.requests_expired = 0           # guarded-by: _cv
+        # a MoE model's (layer, expert) pairs given rows, per program:
+        # (host time it came back, "prefill" | "step", token rows, count)
+        self.experts_touched: collections.deque = collections.deque(
+            maxlen=EXPERT_LOG)              # guarded-by: _cv
 
     # -- submission ---------------------------------------------------------
     def submit(self, prompt, *, max_new_tokens: int = 16,
@@ -522,8 +540,8 @@ class ContinuousBatcher(AsyncWorkerLoop):
 
         def _attempt():
             self._fire("batcher.prefill")
-            logits, cache = self._prefill_fn(
-                self._params, jnp.asarray(req.prompt[None, :]))
+            logits, cache, touched = _outputs(self._prefill_fn(
+                self._params, jnp.asarray(req.prompt[None, :])))
             if self._paged is not None:
                 self._pool = self._write_fn(
                     self._pool, cache, jnp.int32(slot_idx),
@@ -531,14 +549,14 @@ class ContinuousBatcher(AsyncWorkerLoop):
             else:
                 self._pool = self._write_fn(self._pool, cache,
                                             jnp.int32(slot_idx))
-            return np.asarray(logits, np.float32).reshape(-1)
+            return np.asarray(logits, np.float32).reshape(-1), touched
 
         passes = ({} if self._row_blocks is None else
                   {"weight_passes": self._row_blocks(int(req.prompt.size))})
         with tracing.span("batcher.prefill", rid=req.handle.rid,
                           prompt_len=int(req.prompt.size), **passes) as sp:
             try:
-                row = self._guarded(_attempt)
+                row, touched = self._guarded(_attempt)
             except Exception as e:  # noqa: BLE001 — lands on the handle
                 sp.set(error=type(e).__name__)
                 with self._cv:
@@ -546,6 +564,7 @@ class ContinuousBatcher(AsyncWorkerLoop):
                     self._release_pages_locked(slot_idx)
                 req.handle._fail(e)
                 return
+            self._count_experts(sp, "prefill", int(req.prompt.size), touched)
             tok = int(np.argmax(row))
             with self._cv:
                 slot = self._slots[slot_idx]
@@ -612,17 +631,17 @@ class ContinuousBatcher(AsyncWorkerLoop):
                     toks_d, poss_d = jnp.asarray(toks), jnp.asarray(poss)
                 with tracing.span("batcher.dispatch"):
                     self._fire("batcher.decode")
-                    logits, pool = self._step_fn(self._params, pool,
-                                                 toks_d, poss_d)
+                    logits, pool, touched = _outputs(self._step_fn(
+                        self._params, pool, toks_d, poss_d))
                 with tracing.span("batcher.wait"):
                     jax.block_until_ready(logits)
                 with tracing.span("batcher.fetch"):
                     rows = np.asarray(logits, np.float32)
-                return rows, pool
+                return rows, pool, touched
 
             t0 = time.monotonic()
             try:
-                rows, self._pool = self._guarded(_attempt)
+                rows, self._pool, touched = self._guarded(_attempt)
             except Exception as e:  # noqa: BLE001 — exactly this batch
                 step.set(error=type(e).__name__)
                 with self._cv:
@@ -636,6 +655,7 @@ class ContinuousBatcher(AsyncWorkerLoop):
             sup = self._supervisor
             if sup is not None:
                 sup.record_latency(time.monotonic() - t0)
+            self._count_experts(step, "step", self.n_slots, touched)
             with self._cv:
                 self.steps_run += 1
             with tracing.span("batcher.sample"):
@@ -647,6 +667,16 @@ class ContinuousBatcher(AsyncWorkerLoop):
                     s.handle._emit(tok, rows[i].copy() if self.record_logits
                                    else None)
                     self._maybe_retire(i, tok)
+
+    def _count_experts(self, span, kind: str, rows: int, touched) -> None:
+        """Record a MoE program's experts-given-rows count (None for a
+        model without experts) on its span and in ``experts_touched``."""
+        if touched is None:
+            return
+        n = int(touched)
+        span.set(experts_touched=n)
+        with self._cv:
+            self.experts_touched.append((time.monotonic(), kind, rows, n))
 
     def _maybe_retire(self, slot_idx: int, tok: int) -> None:
         with self._cv:
@@ -682,8 +712,8 @@ class ContinuousBatcher(AsyncWorkerLoop):
         eos = self.eos_id if eos_id is None else eos_id
         pool = self._api.init_cache(self.cfg, self.n_slots, self.max_len,
                                     paged=self._paged)
-        logits, cache = self._prefill_fn(self._params,
-                                         jnp.asarray(prompt[None, :]))
+        logits, cache, _ = _outputs(self._prefill_fn(
+            self._params, jnp.asarray(prompt[None, :])))
         if self._paged is not None:
             # deterministic solo allocation: the first pages after
             # scratch.  Physical page ids never enter the math (pages
@@ -708,9 +738,8 @@ class ContinuousBatcher(AsyncWorkerLoop):
             tvec = np.zeros((self.n_slots,), np.int32)
             pvec = np.zeros((self.n_slots,), np.int32)
             tvec[0], pvec[0] = tok, pos
-            logits, pool = self._step_fn(self._params, pool,
-                                         jnp.asarray(tvec),
-                                         jnp.asarray(pvec))
+            logits, pool, _ = _outputs(self._step_fn(
+                self._params, pool, jnp.asarray(tvec), jnp.asarray(pvec)))
             r = np.asarray(logits, np.float32)[0]
             tok, pos = int(np.argmax(r)), pos + 1
             toks.append(tok)
@@ -741,8 +770,8 @@ class ContinuousBatcher(AsyncWorkerLoop):
             raise ValueError("prompt + replay tokens exceed max_len")
         pool = self._api.init_cache(self.cfg, self.n_slots, self.max_len,
                                     paged=self._paged)
-        logits, cache = self._prefill_fn(self._params,
-                                         jnp.asarray(prompt[None, :]))
+        logits, cache, _ = _outputs(self._prefill_fn(
+            self._params, jnp.asarray(prompt[None, :])))
         if self._paged is not None:
             need = self._paged.pages_for(prompt.size + len(tokens))
             row = np.full((self._paged.max_pages,),
@@ -758,9 +787,8 @@ class ContinuousBatcher(AsyncWorkerLoop):
             tvec = np.zeros((self.n_slots,), np.int32)
             pvec = np.zeros((self.n_slots,), np.int32)
             tvec[0], pvec[0] = tok, pos
-            logits, pool = self._step_fn(self._params, pool,
-                                         jnp.asarray(tvec),
-                                         jnp.asarray(pvec))
+            logits, pool, _ = _outputs(self._step_fn(
+                self._params, pool, jnp.asarray(tvec), jnp.asarray(pvec)))
             rows.append(np.asarray(logits, np.float32)[0].copy())
             pos += 1
         return np.stack(rows) if rows else np.zeros((0, 0), np.float32)

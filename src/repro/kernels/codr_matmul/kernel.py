@@ -47,15 +47,16 @@ ROW_CAP = 1024       # most rows one block holds (the longest chat prompt)
 VMEM_LIMIT = 64 << 20        # scoped VMEM a call asks for (a v5e has 128 MiB)
 
 
-def _decode_plane(words: jax.Array, table_ref, shift: int,
-                  bits: int) -> jax.Array:
+def decode_plane(words: jax.Array, level, shift: int,
+                 bits: int) -> jax.Array:
     """Indices at ``shift`` of an int32 word block → their f32 table
-    values (masked table reduction — no gather on the vector unit)."""
+    values, ``level(u)`` being entry ``u`` as a scalar (masked table
+    reduction — no gather on the vector unit)."""
     idx = jax.lax.shift_right_logical(words, jnp.int32(shift)) \
         & jnp.int32((1 << bits) - 1)
 
     def body(u, acc):
-        return acc + jnp.where(idx == u, table_ref[u], 0.0)
+        return acc + jnp.where(idx == u, level(u), 0.0)
 
     return jax.lax.fori_loop(0, 1 << bits, body,
                              jnp.zeros(idx.shape, jnp.float32))
@@ -72,9 +73,8 @@ def _codr_matmul_kernel(x_ref, packed_ref, table_ref, scale_ref, o_ref,
     x_blk = x_ref[...].astype(jnp.float32)
     words = packed_ref[...]
     for s in range(32 // bits):
-        acc_ref[s] += jnp.dot(x_blk, _decode_plane(words, table_ref,
-                                                   s * bits, bits),
-                              preferred_element_type=jnp.float32)
+        w = decode_plane(words, lambda u: table_ref[u], s * bits, bits)
+        acc_ref[s] += jnp.dot(x_blk, w, preferred_element_type=jnp.float32)
 
     @pl.when(k_step == n_k - 1)
     def _done():
@@ -95,6 +95,17 @@ def _block(dim: int, target: int, align: int, *, divides: bool) -> int:
             b -= align
         return b if b >= align else dim
     return b
+
+
+def k_block(k: int, target: int) -> int:
+    """Contraction rows of a block: the largest multiple of 128 up to
+    ``target`` that divides K; where none does (DeepSeek-V2-Lite's dense
+    FFN, K = 10,944 = 64 x 171) the target itself, the last block then
+    ragged: the wrapper pads the activations' K with zeros, so the rows
+    past K of that word block (whatever words, each decoding to a finite
+    table value) add nothing."""
+    b = _block(k, target, LANES, divides=True)
+    return max(target // LANES, 1) * LANES if b == k > target else b
 
 
 def _row_block(m: int) -> int:
@@ -122,6 +133,12 @@ def vmem_bytes(bm: int, bw: int, bk: int, bits: int) -> int:
                 + 2 * bk * bw + bm * bw)
 
 
+def block_targets(rows: int) -> tuple[int, int]:
+    """Output-column and contraction-row targets of the word block that
+    ``rows`` rows share: 2048 x 512 up to 128 rows, 1024 x 256 above."""
+    return (2048, 512) if rows <= 128 else (1024, 256)
+
+
 def _blocks(m: int, k: int, n_words: int, bits: int) -> tuple[int, int, int]:
     """Default ``(bm, bw, bk)`` of an ``(m, k) x (k, n_words)`` call:
     ``bm`` from :func:`_row_block`; ``bw`` and ``bk`` fitted to targets
@@ -130,10 +147,10 @@ def _blocks(m: int, k: int, n_words: int, bits: int) -> tuple[int, int, int]:
     quarter-size word block decoded 4-40% faster per call (TPU v5e,
     qwen2.5-3b projections at 256-1024 rows).  Every width fits
     ``VMEM_LIMIT`` at ``ROW_CAP`` rows (:func:`vmem_bytes`)."""
-    bn, bk = (2048, 512) if m <= 128 else (1024, 256)
+    bn, bk = block_targets(m)
     return (_row_block(m),
             _block(n_words, max(bn // (32 // bits), 1), LANES, divides=False),
-            _block(k, bk, LANES, divides=True))
+            k_block(k, bk))
 
 
 @functools.partial(jax.jit,
@@ -157,8 +174,10 @@ def codr_matmul_pallas(x: jax.Array, packed: jax.Array, table: jax.Array,
     bm = d_bm if bm is None else _block(m, bm, 8, divides=False)
     bw = d_bw if bn is None else _block(n_words, max(bn // per_word, 1),
                                         LANES, divides=False)
-    bk = d_bk if bk is None else _block(k, bk, LANES, divides=True)
-    grid = (pl.cdiv(m, bm), pl.cdiv(n_words, bw), k // bk)
+    bk = d_bk if bk is None else k_block(k, bk)
+    grid = (pl.cdiv(m, bm), pl.cdiv(n_words, bw), pl.cdiv(k, bk))
+    if k % bk:                      # a ragged last K block sums zeros
+        x = jnp.pad(x, ((0, 0), (0, grid[2] * bk - k)))
 
     kernel = functools.partial(_codr_matmul_kernel, bits=bits, n_k=grid[2])
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)

@@ -36,9 +36,9 @@ def get_model(cfg) -> types.SimpleNamespace:
             prefill=prefill, decode_step=encdec.decode_step,
             init_cache=init_cache)
 
-    def prefill(params, batch, cfg):
+    def prefill(params, batch, cfg, **kw):
         return lm.prefill(params, batch["tokens"], cfg,
-                          prefix=batch.get("prefix"))
+                          prefix=batch.get("prefix"), **kw)
 
     def init_cache(cfg, batch, seq, dtype=jnp.bfloat16, paged=None):
         return lm.init_cache(cfg, batch, seq, dtype=dtype, paged=paged)

@@ -14,7 +14,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models import cache as cache_lib
 from repro.models.common import (apply_rope, dense_init, dense_weight,
-                                 linear, norm_apply, norm_init, rms_norm)
+                                 linear, norm_apply, norm_init, rms_norm,
+                                 softmax_scale)
 from repro.sharding import current_ctx, maybe_constrain
 
 
@@ -271,8 +272,8 @@ def _qkv(p, x, cfg, positions):
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"]["w"])
         k = rms_norm(k, p["k_norm"]["w"])
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     if hq % 16 == 0:  # hint only when cleanly divisible by any model axis
         q = maybe_constrain(q, "batch", None, "model", None)
     return q, k, v
@@ -336,14 +337,20 @@ def gqa_cache_init_paged(cfg, spec, dtype=jnp.bfloat16):
 # ---------------------------------------------------------------------------
 
 def mla_init(key, cfg) -> dict:
+    """Without q-LoRA (``q_lora_rank`` 0, DeepSeek-V2-Lite) the query
+    is one ``q_proj``; with it, ``q_a_proj`` → RMSNorm → ``q_b_proj``."""
     ks = jax.random.split(key, 6)
     d, h = cfg.d_model, cfg.n_heads
     dn, dr, dv = cfg.nope_head_dim, cfg.rope_head_dim, cfg.v_head_dim
     qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
+    if qr:
+        q = {"q_a_proj": dense_init(ks[0], d, qr),
+             "q_a_norm": norm_init(qr, "rmsnorm"),
+             "q_b_proj": dense_init(ks[1], qr, h * (dn + dr))}
+    else:
+        q = {"q_proj": dense_init(ks[0], d, h * (dn + dr))}
     return {
-        "q_a_proj": dense_init(ks[0], d, qr),
-        "q_a_norm": norm_init(qr, "rmsnorm"),
-        "q_b_proj": dense_init(ks[1], qr, h * (dn + dr)),
+        **q,
         "kv_a_proj": dense_init(ks[2], d, kr + dr),
         "kv_a_norm": norm_init(kr, "rmsnorm"),
         "kv_b_proj": dense_init(ks[3], kr, h * (dn + dv)),
@@ -355,10 +362,14 @@ def _mla_q(p, x, cfg, positions):
     b, s, _ = x.shape
     h = cfg.n_heads
     dn, dr = cfg.nope_head_dim, cfg.rope_head_dim
-    qa = norm_apply(linear(x, p["q_a_proj"]), p["q_a_norm"], "rmsnorm")
-    q = linear(qa, p["q_b_proj"]).reshape(b, s, h, dn + dr)
+    if "q_proj" in p:
+        q = linear(x, p["q_proj"])
+    else:
+        qa = norm_apply(linear(x, p["q_a_proj"]), p["q_a_norm"], "rmsnorm")
+        q = linear(qa, p["q_b_proj"])
+    q = q.reshape(b, s, h, dn + dr)
     qn, qrot = q[..., :dn], q[..., dn:]
-    qrot = apply_rope(qrot, positions, cfg.rope_theta)
+    qrot = apply_rope(qrot, positions, cfg.rope_theta, cfg.rope_scaling)
     return qn, qrot
 
 
@@ -367,7 +378,8 @@ def _mla_ckv(p, x, cfg, positions):
     kv_a = linear(x, p["kv_a_proj"])
     ckv = norm_apply(kv_a[..., :kr], p["kv_a_norm"], "rmsnorm")
     krot = kv_a[..., kr:][:, :, None, :]                 # (B,S,1,dr)
-    krot = apply_rope(krot, positions, cfg.rope_theta)[:, :, 0]
+    krot = apply_rope(krot, positions, cfg.rope_theta,
+                      cfg.rope_scaling)[:, :, 0]
     return ckv, krot
 
 
@@ -387,7 +399,7 @@ def mla_forward(p, x, cfg, positions, *, causal=True):
     out = flash_attention(q, k, v, causal=causal,
                           q_chunk=cfg.attn_q_chunk,
                           kv_chunk=cfg.attn_kv_chunk,
-                          scale=1.0 / math.sqrt(dn + dr),
+                          scale=softmax_scale(dn + dr, cfg.rope_scaling),
                           acc_dtype=jnp.float32 if cfg.attn_f32
                           else jnp.bfloat16)
     out = linear(out.reshape(b, s, -1), p["o_proj"])
@@ -424,7 +436,7 @@ def mla_decode(p, x, cfg, cache, pos):
                           ckv_dense)
               + _einsum_f32("bqhd,bsd->bhqs", qrot.astype(krot_dense.dtype),
                             krot_dense))
-    scores = scores / math.sqrt(dn + dr)
+    scores = scores * softmax_scale(dn + dr, cfg.rope_scaling)
     posb = jnp.broadcast_to(jnp.asarray(pos), (b,))
     mask = jnp.arange(ckv_dense.shape[1])[None, :] <= posb[:, None]
     scores = jnp.where(mask[:, None, None, :], scores, -1e30)

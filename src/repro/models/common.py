@@ -42,6 +42,24 @@ def linear(x: jax.Array, w, b: jax.Array | None = None) -> jax.Array:
     return y
 
 
+def grouped_linear(x: jax.Array, w, group_sizes: jax.Array, *,
+                   max_group: int | None = None) -> jax.Array:
+    """Rows of ``x`` sorted by expert, ``group_sizes[e]`` of them for
+    expert ``e``, each times its expert's matrix of the ``(E, K, N)``
+    stack ``w`` — the expert matmul every MoE layer routes through.  A
+    plain array is ``lax.ragged_dot``; a packed stack
+    (:class:`repro.core.codr_linear.PackedLinear` with a leading expert
+    dim) resolves through the backend registry's ``grouped_matmul`` and
+    executes from the packed bitstream.  ``max_group`` bounds the rows
+    any one expert holds (the tokens, when each picks distinct
+    experts)."""
+    if isinstance(w, PackedLinear):
+        from repro.core import backends
+        return backends.resolve(w.backend).grouped_matmul(
+            x, group_sizes, w, max_group=max_group)
+    return jax.lax.ragged_dot(x, w.astype(x.dtype), group_sizes)
+
+
 def embedding_lookup(table, tokens: jax.Array,
                      dtype=DEFAULT_DTYPE) -> jax.Array:
     """``table[tokens]`` — the embedding gather every model routes
@@ -118,18 +136,58 @@ def act_fn(name: str):
 # rotary embeddings
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> np.ndarray:
-    return 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> np.ndarray:
+    """Rotary inverse frequencies of a ``head_dim`` rotation.  With a
+    :class:`repro.configs.base.YarnScaling`, DeepSeek-V2's YaRN blend:
+    dims below the correction dim of ``beta_fast`` rotations keep the
+    plain frequency, dims above that of ``beta_slow`` take it over
+    ``factor``, and a linear ramp joins them."""
+    freqs = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+    if scaling is None:
+        return freqs
+    s = scaling
+
+    def corr(rotations):
+        return head_dim * math.log(s.original_max_position_embeddings
+                                   / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(corr(s.beta_fast)), 0)
+    high = min(math.ceil(corr(s.beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / s.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float
-               ) -> jax.Array:
-    """x: (B, S, H, D) — rotate the full head dim."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def softmax_scale(head_dim: int, scaling=None) -> float:
+    """``head_dim ** -0.5``, times ``m(mscale_all_dim) ** 2`` under
+    YaRN (DeepSeek-V2's ``softmax_scale``)."""
+    scale = 1.0 / math.sqrt(head_dim)
+    if scaling is not None and scaling.mscale_all_dim:
+        scale *= yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+    return scale
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
+    """x: (B, S, H, D) — rotate the full head dim (halves, not
+    interleaved pairs).  Under YaRN, cos and sin are scaled by
+    ``m(mscale) / m(mscale_all_dim)``."""
     d = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(d, theta), jnp.float32)       # (D/2,)
+    freqs = jnp.asarray(rope_freqs(d, theta, scaling), jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs    # (B, S, D/2)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -132,6 +132,8 @@ def init_cache(cfg, batch: int, seq: int, dtype=DEFAULT_DTYPE,
 # ---------------------------------------------------------------------------
 
 def _block_apply(lp, x, spec, cfg, mode, cache, pos, positions):
+    """One layer → ``(x, new_cache, experts touched)``; the count is
+    None for a layer without experts."""
     kind, _ffn = spec
     h = norm_apply(x, lp["norm1"], cfg.norm_type, f32=cfg.norm_f32)
     if kind == "attn":
@@ -157,26 +159,44 @@ def _block_apply(lp, x, spec, cfg, mode, cache, pos, positions):
     else:
         raise ValueError(kind)
     x = x + out
+    touched = None
     if "mlp" in lp:
         h = norm_apply(x, lp["norm2"], cfg.norm_type, f32=cfg.norm_f32)
         if "router" in lp["mlp"]:
-            out = moe_mod.moe_forward(lp["mlp"], h, cfg, mode=mode)
+            out, touched = moe_mod.moe_forward(lp["mlp"], h, cfg, mode=mode)
         else:
             out = moe_mod.mlp_forward(lp["mlp"], h, cfg.act)
         x = x + out
     x = constrain_tokens(x)
-    return x, new_cache
+    return x, new_cache, touched
+
+
+def has_experts(cfg) -> bool:
+    """Whether any layer of ``cfg`` routes to experts."""
+    return any(f == "moe" for _, f in cfg.layer_plan())
+
+
+def _add(total, touched):
+    """A running expert count (None: not counting) plus a layer's."""
+    return total if total is None or touched is None else total + touched
 
 
 def forward(params, tokens, cfg, *, mode: str = "train", cache=None,
-            pos=None, prefix=None):
+            pos=None, prefix=None, count_experts: bool = False):
     """tokens (B, S) int32 → (logits, new_cache).
 
     mode='train'  : causal forward, logits for every position, no cache.
     mode='prefill': causal forward, logits for the LAST position, cache out.
     mode='decode' : S == 1, attends into the preallocated cache at ``pos``.
+
+    With ``count_experts`` it returns ``(logits, new_cache, touched)``:
+    the (layer, expert) pairs that received rows in this call, an int32,
+    or None where nothing is counted (no experts, training, or the mesh
+    lanes: ``moe.counts_experts``).
     """
     plan = cfg.layer_plan()
+    counting = (count_experts and mode != "train" and has_experts(cfg)
+                and moe_mod.counts_experts(cfg))
     x = embedding_lookup(params["embed"], tokens, DEFAULT_DTYPE)
     if prefix is not None:
         x = jnp.concatenate([prefix.astype(x.dtype), x], axis=1)
@@ -187,54 +207,63 @@ def forward(params, tokens, cfg, *, mode: str = "train", cache=None,
     new_prologue = []
     for i, lp in enumerate(params.get("prologue", [])):
         c = cache["prologue"][i] if cache else None
-        x, nc = _block_apply(lp, x, ("attn", "dense"), cfg, mode, c, pos,
-                             positions)
+        x, nc, _ = _block_apply(lp, x, ("attn", "dense"), cfg, mode, c, pos,
+                                positions)
         new_prologue.append(nc)
 
+    touched = jnp.int32(0) if counting else None
     if mode == "train":
         def body(xc, period_params):
             for i, spec in enumerate(plan):
-                xc, _ = _block_apply(period_params[f"b{i}"], xc, spec, cfg,
-                                     mode, None, pos, positions)
+                xc, _, _ = _block_apply(period_params[f"b{i}"], xc, spec,
+                                        cfg, mode, None, pos, positions)
             return xc, None
         if cfg.remat:
             body = jax.checkpoint(body)
         x, _ = jax.lax.scan(body, x, params["stack"])
         stack_cache = None
     elif mode == "prefill":
-        def body(xc, period_params):
+        def body(carry, period_params):
+            xc, n = carry if counting else (carry, None)
             caches = {}
             for i, spec in enumerate(plan):
-                xc, nc = _block_apply(period_params[f"b{i}"], xc, spec, cfg,
-                                      mode, None, pos, positions)
+                xc, nc, t = _block_apply(period_params[f"b{i}"], xc, spec,
+                                         cfg, mode, None, pos, positions)
                 caches[f"b{i}"] = nc
-            return xc, caches
-        x, stack_cache = jax.lax.scan(body, x, params["stack"])
+                n = _add(n, t)
+            return ((xc, n) if counting else xc), caches
+        carry, stack_cache = jax.lax.scan(
+            body, (x, touched) if counting else x, params["stack"])
+        x, touched = carry if counting else (carry, None)
     else:  # decode
         # the cache rides in the scan CARRY with per-period in-place index
         # updates (donation-friendly; scan-ys stacking round-trips the
         # whole cache through a staging buffer on some backends)
         def body(carry, xs):
-            xc, cache_stack = carry
+            xc, cache_stack, *n = carry
+            n = n[0] if counting else None
             period_params, idx = xs
             period_cache = jax.tree.map(
                 lambda buf: jax.lax.dynamic_index_in_dim(
                     buf, idx, 0, keepdims=False), cache_stack)
             new_caches = {}
             for i, spec in enumerate(plan):
-                xc, nc = _block_apply(period_params[f"b{i}"], xc, spec, cfg,
-                                      mode, period_cache[f"b{i}"], pos,
-                                      positions)
+                xc, nc, t = _block_apply(period_params[f"b{i}"], xc, spec,
+                                         cfg, mode, period_cache[f"b{i}"],
+                                         pos, positions)
                 new_caches[f"b{i}"] = nc
+                n = _add(n, t)
             cache_stack = jax.tree.map(
                 lambda buf, nc: jax.lax.dynamic_update_index_in_dim(
                     buf, nc.astype(buf.dtype), idx, 0),
                 cache_stack, new_caches)
-            return (xc, cache_stack), None
+            return (xc, cache_stack) + ((n,) if counting else ()), None
 
-        (x, stack_cache), _ = jax.lax.scan(
-            body, (x, cache["stack"]),
+        carry, _ = jax.lax.scan(
+            body, (x, cache["stack"]) + ((touched,) if counting else ()),
             (params["stack"], jnp.arange(cfg.n_periods)))
+        x, stack_cache = carry[:2]
+        touched = carry[2] if counting else None
 
     x = norm_apply(x, params["final_norm"], cfg.norm_type,
                    f32=cfg.norm_f32)
@@ -248,6 +277,8 @@ def forward(params, tokens, cfg, *, mode: str = "train", cache=None,
         new_cache = {"stack": stack_cache}
         if cfg.n_dense_layers:
             new_cache["prologue"] = new_prologue
+    if count_experts:
+        return logits, new_cache, touched
     return logits, new_cache
 
 
@@ -265,12 +296,14 @@ def train_loss(params, batch, cfg):
                         mask[:, 1:] if mask is not None else None)
 
 
-def prefill(params, tokens, cfg, prefix=None):
-    return forward(params, tokens, cfg, mode="prefill", prefix=prefix)
+def prefill(params, tokens, cfg, prefix=None, count_experts: bool = False):
+    return forward(params, tokens, cfg, mode="prefill", prefix=prefix,
+                   count_experts=count_experts)
 
 
-def decode_step(params, cache, token, pos, cfg):
-    """token (B,) int32, pos scalar int32 → (logits (B, V), cache)."""
-    logits, cache = forward(params, token[:, None], cfg, mode="decode",
-                            cache=cache, pos=pos)
-    return logits[:, 0], cache
+def decode_step(params, cache, token, pos, cfg, count_experts: bool = False):
+    """token (B,) int32, pos scalar int32 → (logits (B, V), cache), and
+    with ``count_experts`` the experts given rows (``forward``)."""
+    logits, *rest = forward(params, token[:, None], cfg, mode="decode",
+                            cache=cache, pos=pos, count_experts=count_experts)
+    return (logits[:, 0], *rest)

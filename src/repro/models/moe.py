@@ -1,12 +1,15 @@
 """Dense MLP and Mixture-of-Experts with expert parallelism.
 
-MoE dispatch is sort-based + ``lax.ragged_dot`` (active-expert FLOPs
+MoE dispatch is sort-based + a grouped matmul (active-expert FLOPs
 only — no one-hot dispatch einsum, keeping the roofline's useful-FLOPs
-ratio honest).  Under a mesh, experts are sharded over the ``model`` axis
-via ``shard_map``: tokens (already sharded over ``data``) are processed
-against the *local* expert slice and partial outputs are ``psum``-combined
-over ``model`` — one all-reduce per MoE layer, the same collective class
-as TP, with no data-dependent all-to-all sizes.
+ratio honest): ``lax.ragged_dot`` on dense stacks, and on packed stacks
+the backend's ``grouped_matmul`` (``codr_matmul``'s grouped kernel
+decodes only the experts that received rows).  Under a mesh, experts
+are sharded over the ``model`` axis via ``shard_map``: tokens (already
+sharded over ``data``) are processed against the *local* expert slice
+and partial outputs are ``psum``-combined over ``model`` — one
+all-reduce per MoE layer, the same collective class as TP, with no
+data-dependent all-to-all sizes.
 """
 from __future__ import annotations
 
@@ -16,13 +19,12 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import (PackedLinear, act_fn, dense_init,
-                                 dense_weight, linear)
+                                 dense_weight, grouped_linear, linear)
 from repro.sharding import current_ctx
 
-# router logits + expert stacks consume raw weight arrays (jnp.dot with
-# explicit f32 casts, lax.ragged_dot, shard_map operands) rather than a
-# single matmul a backend could intercept — packed leaves are decoded
-# once per forward here (decode-on-dispatch, docs/DESIGN.md §2)
+# the mesh lanes hand the router and expert stacks to shard_map as raw
+# operands, so there a packed leaf is decoded once per forward
+# (decode-on-dispatch, docs/DESIGN.md §2); one device keeps them packed
 _PACKABLE_KEYS = ("router", "w_experts_gate", "w_experts_in",
                   "w_experts_out")
 
@@ -78,23 +80,38 @@ def moe_init(key, cfg) -> dict:
 
 
 def _expert_compute(xs: jax.Array, group_sizes: jax.Array, wg, wi, wo,
-                    act: str) -> jax.Array:
+                    act: str, max_group: int) -> jax.Array:
     """Grouped SwiGLU over sorted tokens: xs (T, d), experts (E, d, f)."""
-    gate = jax.lax.ragged_dot(xs, wg.astype(xs.dtype), group_sizes)
-    up = jax.lax.ragged_dot(xs, wi.astype(xs.dtype), group_sizes)
+    gate = grouped_linear(xs, wg, group_sizes, max_group=max_group)
+    up = grouped_linear(xs, wi, group_sizes, max_group=max_group)
     h = act_fn(act)(gate) * up
-    return jax.lax.ragged_dot(h, wo.astype(xs.dtype), group_sizes)
+    return grouped_linear(h, wo, group_sizes, max_group=max_group)
+
+
+def route(logits: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
+    """``(gates, expert ids)``, each ``(T, k)``, from f32 router logits
+    ``(T, E)``, by ``cfg.moe_gating``: ``"topk_softmax"`` takes the top
+    k logits and a softmax over them; ``"softmax_topk"`` a softmax over
+    all E, its top k, not renormalised, times ``cfg.moe_routed_scale``."""
+    k = cfg.moe_top_k
+    if cfg.moe_gating == "softmax_topk":
+        gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        return gates * cfg.moe_routed_scale, idx
+    if cfg.moe_gating != "topk_softmax":
+        raise ValueError(f"unknown moe_gating {cfg.moe_gating!r}")
+    gates, idx = jax.lax.top_k(logits, k)
+    return jax.nn.softmax(gates, axis=-1), idx
 
 
 def _moe_local(x2d: jax.Array, p, cfg, n_local: int, expert_offset
-               ) -> jax.Array:
+               ) -> tuple[jax.Array, jax.Array]:
     """Token-choice top-k against ``n_local`` experts starting at
-    ``expert_offset`` (traced).  x2d (T, d) → (T, d) partial output."""
+    ``expert_offset`` (traced).  x2d (T, d) → ((T, d) partial output,
+    how many of the local experts received rows)."""
     t, d = x2d.shape
     k = cfg.moe_top_k
-    logits = jnp.dot(x2d.astype(jnp.float32), p["router"].astype(jnp.float32))
-    gates, idx = jax.lax.top_k(logits, k)                  # (T, k)
-    gates = jax.nn.softmax(gates, axis=-1)
+    logits = linear(x2d.astype(jnp.float32), p["router"])   # f32 (T, E)
+    gates, idx = route(logits, cfg)                        # (T, k)
 
     flat_idx = idx.reshape(-1)                             # (T*k,)
     flat_gate = gates.reshape(-1)
@@ -106,14 +123,16 @@ def _moe_local(x2d: jax.Array, p, cfg, n_local: int, expert_offset
     xs = jnp.take(x2d, token_of, axis=0)                   # (T*k, d)
     group_sizes = jnp.bincount(jnp.where(is_local, local_id, n_local),
                                length=n_local + 1)[:n_local]
+    # a token picks k distinct experts: no group holds more than T rows
     ys = _expert_compute(xs, group_sizes, p["w_experts_gate"],
-                         p["w_experts_in"], p["w_experts_out"], cfg.act)
+                         p["w_experts_in"], p["w_experts_out"], cfg.act,
+                         max_group=t)
     # zero contributions from remote/padding rows
     in_range = jnp.arange(t * k) < group_sizes.sum()
     ys = jnp.where(in_range[:, None], ys, 0.0)
     ys = ys * jnp.take(flat_gate, order).astype(ys.dtype)[:, None]
     out = jnp.zeros((t, d), ys.dtype).at[token_of].add(ys)
-    return out
+    return out, jnp.count_nonzero(group_sizes).astype(jnp.int32)
 
 
 def _moe_2d(p, x, cfg, ctx):
@@ -140,7 +159,7 @@ def _moe_2d(p, x, cfg, ctx):
         offset = jax.lax.axis_index("model") * n_local
         pl_ = {"router": router, "w_experts_gate": wg,
                "w_experts_in": wi, "w_experts_out": wo}
-        part = _moe_local(xg, pl_, cfg, n_local, offset)
+        part, _ = _moe_local(xg, pl_, cfg, n_local, offset)
         full = jax.lax.psum(part, ("data", "model"))
         t_loc = x2d.shape[0]
         start = jax.lax.axis_index("data") * t_loc
@@ -159,29 +178,42 @@ def _moe_2d(p, x, cfg, ctx):
     return out2d.reshape(b, s, d).astype(x.dtype)
 
 
+def _local_lane(cfg, ctx) -> bool:
+    """Whether ``moe_forward`` runs every expert on this device."""
+    return (ctx is None or ctx.axis_size("model") == 1
+            or cfg.n_experts % ctx.axis_size("model") != 0)
+
+
+def counts_experts(cfg) -> bool:
+    """Whether ``moe_forward`` counts the experts given rows: on one
+    device; the mesh lanes, which no caller reads a count from, return
+    None."""
+    return _local_lane(cfg, current_ctx())
+
+
 def moe_forward(p, x, cfg, mode: str = "train"):
-    """x (B, S, d) → (B, S, d).  EP over 'model' when a mesh is active;
-    2-D expert sharding for decode when ``cfg.moe_decode_2d``."""
-    p = _dense_moe_params(p)
+    """x (B, S, d) → ((B, S, d), how many experts received rows, None on
+    the mesh lanes).  EP over 'model' when a mesh is active; 2-D expert
+    sharding for decode when ``cfg.moe_decode_2d``."""
     b, s, d = x.shape
     ctx = current_ctx()
     e = cfg.n_experts
-
-    def run_local(x2d):
-        return _moe_local(x2d, p, cfg, e, 0)
 
     if (cfg.moe_decode_2d and mode == "decode" and ctx is not None
             and ctx.axis_size("model") > 1 and ctx.axis_size("data") > 1
             and e % ctx.axis_size("model") == 0
             and cfg.moe_d_ff % ctx.axis_size("data") == 0):
+        p = _dense_moe_params(p)
         out = _moe_2d(p, x, cfg, ctx)
         if "shared" in p:
             out = out + mlp_forward(p["shared"], x, cfg.act)
-        return out
+        return out, None
 
-    if ctx is None or ctx.axis_size("model") == 1 or e % ctx.axis_size("model"):
-        out = run_local(x.reshape(-1, d)).reshape(b, s, d).astype(x.dtype)
+    if _local_lane(cfg, ctx):
+        out, touched = _moe_local(x.reshape(-1, d), p, cfg, e, 0)
+        out = out.reshape(b, s, d).astype(x.dtype)
     else:
+        p = _dense_moe_params(p)
         mesh = ctx.mesh
         msize = ctx.axis_size("model")
         n_local = e // msize
@@ -200,7 +232,7 @@ def moe_forward(p, x, cfg, mode: str = "train"):
             my = jax.lax.axis_index("model")
             pl_ = {"router": router, "w_experts_gate": wg,
                    "w_experts_in": wi, "w_experts_out": wo}
-            part = _moe_local(x2d, pl_, cfg, n_local, my * n_local)
+            part, _ = _moe_local(x2d, pl_, cfg, n_local, my * n_local)
             return jax.lax.psum(part, "model")
 
         specs_w = (P(None, None), P("model", None, None),
@@ -213,7 +245,8 @@ def moe_forward(p, x, cfg, mode: str = "train"):
         )(x.reshape(-1, d), p["router"], p["w_experts_gate"],
           p["w_experts_in"], p["w_experts_out"])
         out = out2d.reshape(b, s, d).astype(x.dtype)
+        touched = None
 
     if "shared" in p:
         out = out + mlp_forward(p["shared"], x, cfg.act)
-    return out
+    return out, touched

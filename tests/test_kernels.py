@@ -109,6 +109,99 @@ def test_codr_matmul_chat_prompts_one_pass():
                     kernel.VMEM_LIMIT, (m, bits, k, n)
 
 
+def test_codr_matmul_ragged_k_block(rng):
+    """A K with no multiple of 128 among its divisors (DeepSeek-V2-Lite's
+    dense FFN: 10,944 = 64 x 171) takes target-sized K blocks, the last
+    one ragged over activations padded with zeros."""
+    assert kernel.k_block(10944, 256) == 256
+    assert kernel.k_block(11008, 256) == 256 and kernel.k_block(2048, 512) \
+        == 512
+    pw = _packed(rng, 704, 256, 16)             # 704 = 64 x 11 > 512
+    assert kernel._blocks(8, 704, 32, 4)[2] == 512
+    x = jnp.asarray(rng.normal(size=(8, 704)).astype(np.float32))
+    y = codr_matmul(x, pw, interpret=True)
+    yr = codr_matmul_ref(x, pw.packed, pw.table, pw.scale.reshape(-1),
+                         bits=pw.bits, n=256)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
+                               rtol=2e-3, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# codr_gmm (grouped kernel over a packed expert stack)
+# ---------------------------------------------------------------------------
+
+def _expert_stack(rng, e, k, n):
+    from repro.core.codr_linear import pack_projection
+    w = rng.normal(size=(e, k, n)).astype(np.float32)
+    return pack_projection(w, n_unique=16).weight
+
+
+@pytest.mark.parametrize("sizes", [
+    [5, 0, 17, 3, 0, 9, 1, 12],          # uneven, empty experts
+    [0, 0, 0, 40, 0, 0, 0, 0],           # every row to one expert
+    [0, 0, 0, 0, 0, 0, 0, 1],            # one row, the last expert
+    [33, 1, 0, 30],                      # groups across a row block
+])
+def test_codr_gmm_matches_decode_then_ragged_dot(sizes, rng):
+    """Each row times its own expert's decoded matrix, as decoding the
+    whole stack and ``lax.ragged_dot`` give it; rows past the groups'
+    total come out 0."""
+    from repro.kernels.codr_gmm import codr_gmm
+    e, k, n = len(sizes), 256, 256
+    pw = _expert_stack(rng, e, k, n)
+    m = sum(sizes) + 3
+    x = jnp.asarray(rng.normal(size=(m, k)).astype(np.float32))
+    gs = jnp.asarray(sizes, jnp.int32)
+    y = codr_gmm(x, gs, pw, interpret=True)
+    dense = jax.vmap(lambda p, t: unpack_unique(p, t, bits=pw.bits, n=n))(
+        pw.packed, pw.table) * pw.scale[:, None, None]
+    yr = np.array(jax.lax.ragged_dot(x, dense, gs,
+                                     precision=jax.lax.Precision.HIGHEST))
+    yr[sum(sizes):] = 0.0
+    np.testing.assert_allclose(np.asarray(y), yr, rtol=2e-3, atol=2e-4)
+
+
+def test_codr_gmm_more_than_one_row_block(rng, monkeypatch):
+    """An expert holding more rows than one row block takes several,
+    each decoding the expert's blocks again (``codr_matmul``'s rule);
+    rows and experts past a block's end are skipped."""
+    from repro.kernels.codr_gmm import codr_gmm
+    monkeypatch.setattr(kernel, "ROW_CAP", 16)
+    sizes = [20, 0, 37, 5]
+    pw = _expert_stack(rng, 4, 128, 256)
+    x = jnp.asarray(rng.normal(size=(sum(sizes), 128)).astype(np.float32))
+    gs = jnp.asarray(sizes, jnp.int32)
+    assert kernel.row_blocks(37) == 3
+    y = codr_gmm(x, gs, pw, max_group=37, interpret=True)
+    dense = jax.vmap(lambda p, t: unpack_unique(p, t, bits=pw.bits, n=256))(
+        pw.packed, pw.table) * pw.scale[:, None, None]
+    yr = jax.lax.ragged_dot(x, dense, gs,
+                            precision=jax.lax.Precision.HIGHEST)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=2e-3,
+                               atol=2e-4)
+
+
+def test_codr_gmm_skipped_experts_read_no_words():
+    """An expert without rows points its weight blocks at the block
+    already in VMEM: its own last, the nearest earlier expert's last,
+    or, before any expert with rows, the first one's first."""
+    from repro.kernels.codr_gmm.kernel import _weight_source
+    widx, wlast = _weight_source(jnp.asarray([0, 0, 4, 0, 2, 0]))
+    assert widx.tolist() == [2, 2, 2, 2, 4, 4]
+    assert wlast.tolist() == [0, 0, 1, 1, 1, 1]
+
+
+def test_codr_gmm_layout_aligns_groups():
+    """Groups start on the sublane tile; each row lands in its group's
+    span, rows past the total nowhere; one row block of room is left."""
+    from repro.kernels.codr_gmm.kernel import ALIGN, layout
+    starts, dest, m_tot = layout(jnp.asarray([3, 0, 9, 1]), 15, 16)
+    assert starts.tolist() == [0, 8, 8, 24]
+    assert dest.tolist() == [0, 1, 2, 8, 9, 10, 11, 12, 13, 14, 15, 16, 24,
+                             m_tot, m_tot]
+    assert m_tot % ALIGN == 0 and m_tot >= 24 + 16
+
+
 def test_pack_unpack_roundtrip(rng):
     for n_unique in (2, 4, 16, 256):
         w = rng.normal(size=(32, 64)).astype(np.float32)

@@ -56,7 +56,8 @@ def test_arch_smoke_serve_step(arch, key):
 
 
 @pytest.mark.parametrize("arch", ["qwen3-32b", "deepseek-v2-236b",
-                                  "jamba-v0.1-52b", "xlstm-350m"])
+                                  "deepseek-v2-lite", "jamba-v0.1-52b",
+                                  "xlstm-350m"])
 def test_decode_matches_prefill_f32(arch, key):
     """Incremental decode must reproduce the parallel forward exactly
     (f32; bf16 differs only by rounding — verified manually)."""
@@ -92,7 +93,7 @@ def test_moe_routing_is_topk(key):
     cfg = smoke_variant(get_config("granite-moe-1b-a400m"))
     p = moe_mod.moe_init(key, cfg)
     x = jax.random.normal(key, (1, 8, cfg.d_model), jnp.float32)
-    out1 = moe_mod.moe_forward(p, x, cfg)
+    out1, _ = moe_mod.moe_forward(p, x, cfg)
     logits = jnp.dot(x.reshape(-1, cfg.d_model),
                      p["router"].astype(jnp.float32))
     _, used = jax.lax.top_k(logits, cfg.moe_top_k)
@@ -101,7 +102,7 @@ def test_moe_routing_is_topk(key):
     if unused:
         p2 = dict(p)
         p2["w_experts_in"] = p["w_experts_in"].at[unused[0]].set(123.0)
-        out2 = moe_mod.moe_forward(p2, x, cfg)
+        out2, _ = moe_mod.moe_forward(p2, x, cfg)
         np.testing.assert_allclose(np.asarray(out1), np.asarray(out2))
 
 

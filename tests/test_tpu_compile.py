@@ -65,6 +65,41 @@ def test_codr_matmul_compiles_for_v5e(m, k, n, one_chip, no_compile_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# DeepSeek-V2-Lite's expert stacks (K, N): gate/up, down; 64 experts.
+# Rows: the pooled decode step's 64 slots x top-6, and a 1,024-token
+# prompt's 1,024 x 6, with the most one expert can hold (the tokens)
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+@pytest.mark.parametrize("m,max_group", [(384, 64), (6144, 1024)])
+def test_codr_gmm_compiles_for_v5e(m, max_group, k, n, one_chip,
+                                   no_compile_cache):
+    from repro.kernels.codr_gmm.kernel import codr_gmm_pallas
+    bits, e = 4, 64
+    args = (_shape(one_chip, (m, k), jnp.bfloat16),
+            _shape(one_chip, (e,), jnp.int32),
+            _shape(one_chip, (e, k, n * bits // 32), jnp.uint32),
+            _shape(one_chip, (e, 1 << bits), jnp.float32),
+            _shape(one_chip, (e,), jnp.float32))
+    compiled = jax.jit(lambda x, g, p, t, s: codr_gmm_pallas(
+        x, g, p, t, s, bits=bits, n=n, max_group=max_group)).lower(
+            *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_codr_matmul_ragged_k_compiles_for_v5e(one_chip, no_compile_cache):
+    """DeepSeek-V2-Lite's dense down projection (K = 10,944, no multiple
+    of 128 divides it) at a 1,024-token prompt: ragged K blocks fit the
+    scoped VMEM where one whole-K block did not."""
+    from repro.kernels.codr_matmul.kernel import codr_matmul_pallas
+    bits, m, k, n = 4, 1024, 10944, 2048
+    args = (_shape(one_chip, (m, k), jnp.float32),
+            _shape(one_chip, (k, n * bits // 32), jnp.uint32),
+            _shape(one_chip, (1 << bits,), jnp.float32),
+            _shape(one_chip, (1,), jnp.float32))
+    compiled = jax.jit(lambda x, p, t, s: codr_matmul_pallas(
+        x, p, t, s, bits=bits, n=n)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_smm_conv_refused_for_v5e(one_chip, no_compile_cache):
     """``smm_kernel`` is not offered on a TPU because Mosaic refuses the
     kernel (``TPU_REFUSAL``); this pins that reason to the compiler's
@@ -81,21 +116,14 @@ def test_smm_conv_refused_for_v5e(one_chip, no_compile_cache):
         fn.lower(*args).compile()
 
 
-def test_qwen_pooled_decode_step_compiles_for_v5e(one_chip, no_compile_cache,
-                                                  monkeypatch):
-    """The whole pooled decode step of 4 full-width qwen2.5-3b layers,
-    from packed weights (given as shapes) and an int8 paged KV pool:
-    every projection lowers to the Mosaic kernel."""
-    from repro.configs import get_config
+def _served_shapes(cfg, one_chip, n_slots, max_len):
+    """Packed params (given as shapes, in ``compile_params``' leaf rules)
+    and an int8 paged KV pool of ``cfg``, on the described chip."""
     from repro.core.api import EMBED_INCLUDE, PACK_INCLUDE
     from repro.core.codr_linear import (PackedEmbedding, PackedLinear,
                                         PackedWeight)
-    from repro.kernels.codr_matmul import ops as mm_ops
     from repro.models import cache as cache_mod
     from repro.models import get_model
-    # the kernel wrapper asks the default backend (the CPU here)
-    monkeypatch.setattr(mm_ops, "_on_tpu", lambda: True)
-    cfg = dataclasses.replace(get_config("qwen2.5-3b"), n_layers=4)
     api = get_model(cfg)
 
     def packed(shape):
@@ -118,14 +146,54 @@ def test_qwen_pooled_decode_step_compiles_for_v5e(one_chip, no_compile_cache,
 
     params = jax.tree_util.tree_map_with_path(leaf, jax.eval_shape(
         lambda: api.init_params(jax.random.PRNGKey(0), cfg)))
-    n_slots, max_len = 8, 320
     spec = cache_mod.PagedSpec(page_size=16, max_len=max_len,
                                n_slots=n_slots, kv_dtype="int8")
     pool = jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype),
                         jax.eval_shape(lambda: api.init_cache(
                             cfg, n_slots, max_len, paged=spec)))
+    return api, params, pool
+
+
+def test_qwen_pooled_decode_step_compiles_for_v5e(one_chip, no_compile_cache,
+                                                  monkeypatch):
+    """The whole pooled decode step of 4 full-width qwen2.5-3b layers,
+    from packed weights (given as shapes) and an int8 paged KV pool:
+    every projection lowers to the Mosaic kernel."""
+    from repro.configs import get_config
+    from repro.kernels.codr_matmul import ops as mm_ops
+    # the kernel wrapper asks the default backend (the CPU here)
+    monkeypatch.setattr(mm_ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("qwen2.5-3b"), n_layers=4)
+    n_slots = 8
+    api, params, pool = _served_shapes(cfg, one_chip, n_slots, 320)
     vec = _shape(one_chip, (n_slots,), jnp.int32)
     compiled = jax.jit(lambda p, c, t, i: api.decode_step(
         p, c, t, i, cfg)).lower(params, pool, vec, vec).compile()
     # q, k, v, o, gate, up, down in the scanned layer body
     assert compiled.as_text().count("tpu_custom_call") >= 7
+
+
+def test_dsv2_lite_pooled_decode_step_compiles_for_v5e(one_chip,
+                                                       no_compile_cache,
+                                                       monkeypatch):
+    """DeepSeek-V2-Lite's pooled decode step at published widths, its
+    dense layer 0 and two MoE layers, 64 slots of an int8 paged latent
+    pool: projections through ``codr_matmul``, expert stacks through
+    ``codr_gmm``, and the program fits one chip's memory."""
+    from repro.configs import get_config
+    from repro.kernels.codr_gmm import ops as gmm_ops
+    from repro.kernels.codr_matmul import ops as mm_ops
+    monkeypatch.setattr(mm_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(gmm_ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), n_layers=3)
+    n_slots = 64
+    api, params, pool = _served_shapes(cfg, one_chip, n_slots, 1280)
+    vec = _shape(one_chip, (n_slots,), jnp.int32)
+    # as the batcher serves it: with the experts' count
+    compiled = jax.jit(lambda p, c, t, i: api.decode_step(
+        p, c, t, i, cfg, count_experts=True)).lower(
+            params, pool, vec, vec).compile()
+    text = compiled.as_text()
+    assert "%codr_gmm" in text and text.count("tpu_custom_call") >= 10
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30
